@@ -54,24 +54,10 @@ def build_plan_8dev(wf):
     and training tasks on 4-7, actor training explicitly DP=2/TP=2."""
     topo = topology.build_testbed("single_region",
                                   counts={"A100": 4, "L4": 4})
-    grouping = next(g for g in enum_mod.priority_groupings(wf)
-                    if len(g) == 2 and any(
-                        wf.task(t).kind == workflow.TaskKind.GEN
-                        for t in min(g, key=len)))
-    gen_gi = next(gi for gi, g in enumerate(grouping)
-                  if any(wf.task(t).kind == workflow.TaskKind.GEN
-                         for t in g))
-    sizes = [4, 4]
-    parallel = {}
-    for t in range(wf.n_tasks):
-        kind = wf.task(t).kind
-        parallel[t] = (2, 1, 2) if kind in (workflow.TaskKind.GEN,
-                                            workflow.TaskKind.TRAIN) \
-            else (4, 1, 1)
-    order = list(range(8)) if gen_gi == 0 else \
-        list(range(4, 8)) + list(range(4))
-    plan = enum_mod.build_plan(topo, wf, grouping, sizes, order,
-                               parallel=parallel)
+    kinds = workflow.TaskKind
+    plan = enum_mod.gen_rest_plan(
+        topo, wf, range(4), range(4, 8),
+        {kinds.GEN: (2, 1, 2), kinds.TRAIN: (2, 1, 2), kinds.INF: (4, 1, 1)})
     ok, msg = check_constraints(topo, wf, plan)
     assert ok, msg
     return topo, plan

@@ -229,3 +229,19 @@ def build_plan(topo: Topology, wf: RLWorkflow, grouping,
                 need = dp * pp * tp
             assignment[t] = np.array(order[:need]).reshape(dp, pp, tp)
     return Plan(tuple(groups), parallel, assignment)
+
+
+def gen_rest_plan(topo: Topology, wf: RLWorkflow,
+                  gen_devices: Sequence[int], rest_devices: Sequence[int],
+                  parallel: Dict[TaskKind, Tuple[int, int, int]]) -> Plan:
+    """Disaggregated plan: the GEN task alone on ``gen_devices``, every
+    other task colocated on ``rest_devices``; ``parallel`` gives each
+    task kind's (dp, pp, tp)."""
+    gen = tuple(t for t in range(wf.n_tasks)
+                if wf.task(t).kind == TaskKind.GEN)
+    rest = tuple(t for t in range(wf.n_tasks) if t not in gen)
+    return build_plan(topo, wf, (gen, rest),
+                      [len(gen_devices), len(rest_devices)],
+                      list(gen_devices) + list(rest_devices),
+                      parallel={t: parallel[wf.task(t).kind]
+                                for t in range(wf.n_tasks)})

@@ -221,11 +221,36 @@ SCENARIOS = ["single_region", "multi_region_hybrid", "multi_country",
 
 
 def build_host(n: int, spec: GPUSpec = A100) -> Topology:
-    """Homogeneous single-machine topology with `n` devices — the proxy
-    the execution engine plans against when no scheduler plan is given
-    (one device id per local accelerator)."""
+    """Homogeneous single-machine topology with `n` devices of `spec`."""
     devices = [Device(i, spec, 0, 0, "local") for i in range(n)]
     return _build(devices, {})
+
+
+# Accelerator specs keyed by ``device_kind`` as JAX reports it.  The one
+# table the planner prices real chips from; a kind missing here is an
+# error, never a default.
+DEVICE_KIND_SPECS: Dict[str, GPUSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v4": TPU_V4,
+}
+
+
+def build_local(devices: Sequence) -> Topology:
+    """The machine this process runs on, one plan device per JAX device.
+
+    TPU chips are priced by their ``device_kind`` through
+    ``DEVICE_KIND_SPECS``.  CPU devices (the test suite and the paper's
+    CPU reproduction runs) have no accelerator spec and are priced as
+    A100s, the paper's reference GPU."""
+    devices = list(devices)
+    if all(d.platform == "cpu" for d in devices):
+        return build_host(len(devices))
+    unknown = {d.device_kind for d in devices} - set(DEVICE_KIND_SPECS)
+    if unknown:
+        raise ValueError(f"no spec for device kind(s) {sorted(unknown)}; "
+                         f"known kinds: {sorted(DEVICE_KIND_SPECS)}")
+    return _build([Device(i, DEVICE_KIND_SPECS[d.device_kind], 0, 0, "local")
+                   for i, d in enumerate(devices)], {})
 
 
 # ---------------------------------------------------------------------------

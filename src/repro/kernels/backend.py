@@ -1,35 +1,18 @@
 """Backend-aware Pallas execution mode.
 
 Every kernel wrapper defaults its ``interpret`` flag to
-``default_interpret()``: compiled Pallas on TPU (the TARGET
-configuration), interpreter mode everywhere else (CPU CI, tests).  The
-``REPRO_PALLAS_INTERPRET`` environment variable overrides in both
-directions ("1"/"true" forces interpret, "0"/"false" forces compiled —
-e.g. to smoke-test lowering on a TPU-less build host).
+``default_interpret()``: compiled Pallas on TPU, interpreter mode on every
+other backend (the CPU test suite).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
-_TRUTHY = ("1", "true", "yes", "on")
-_FALSY = ("0", "false", "no", "off")
-
 
 def default_interpret() -> bool:
     """True when Pallas kernels should run in interpreter mode."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        low = env.strip().lower()
-        if low in _TRUTHY:
-            return True
-        if low in _FALSY:
-            return False
-        raise ValueError(
-            f"REPRO_PALLAS_INTERPRET={env!r}: expected one of "
-            f"{_TRUTHY + _FALSY}")
     return jax.default_backend() != "tpu"
 
 

@@ -2,8 +2,17 @@
 KV cache, K-blocked online softmax with an explicit slot-validity mask
 (the caller derives it from ring positions / causal window).
 
-Grid (B, H, nk); kv blocks iterate innermost with running stats in VMEM
-scratch.  GQA via kv head = h // g.
+Grid (B, KV, nk); kv blocks iterate innermost with running stats in VMEM
+scratch.  Each step serves one GQA group: the g query heads that share
+kv head ``kv`` are scored together against a ``(bk, hd)`` key tile.
+
+Layouts keep every block's last two dims on the TPU's (8, 128) tiling
+rule (or whole): q is ``[B, KV, g, hd]`` (block ``(g, hd)``, the whole
+trailing dims), k/v are ``[B, L, KV*hd]`` (block ``(bk, hd)``, one kv
+head's column tile) and the bias is ``[B, 1, L]`` (block ``(1, bk)``).
+ops.py produces them by reshaping; on TPU the k/v merge is a relayout
+copy of the cache, because the tiled layout of ``[.., KV, hd]`` tiles the
+head axis.
 """
 from __future__ import annotations
 
@@ -29,39 +38,37 @@ def _decode_kernel(q_ref, k_ref, v_ref, bias_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)             # [hd]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # [bk, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    bias = bias_ref[0, :].astype(jnp.float32)          # [bk] additive mask
+    q = q_ref[...].astype(jnp.float32)                 # [g, hd]
+    k = k_ref[...].astype(jnp.float32)                 # [bk, hd]
+    v = v_ref[...].astype(jnp.float32)
+    bias = bias_ref[...].astype(jnp.float32)           # [1, bk] additive
 
-    s = (k @ q) * scale                                # [bk]
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     if cap is not None:
         s = cap * jnp.tanh(s / cap)
-    s = s + bias
-    s2 = s[None, :]                                    # [1, bk]
+    s = s + bias                                       # [g, bk]
 
     m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s2, axis=1, keepdims=True))
-    p = jnp.exp(s2 - m_new)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = acc_scr[...] * corr + p @ v
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(p, v)
     m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[0, 0, :] = (
-            acc_scr[0] / jnp.maximum(l_scr[0, 0], 1e-30)
+        o_ref[...] = (
+            acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
 def decode_attention_kernel(q, k, v, bias, *, cap: Optional[float] = None,
                             bk: int = 512, interpret: bool = True):
-    """q: [B, H, hd]; k/v: [B, L, KV, hd]; bias: [B, L] additive
-    (0 = attend, NEG_INF = masked).  Returns [B, H, hd]."""
-    B, H, hd = q.shape
-    L, KV = k.shape[1], k.shape[2]
-    g = H // KV
+    """q: [B, KV, g, hd]; k/v: [B, L, KV*hd]; bias: [B, 1, L] additive
+    (0 = attend, NEG_INF = masked).  Returns [B, KV, g, hd]."""
+    B, KV, g, hd = q.shape
+    L = k.shape[1]
     bk = min(bk, L)
     assert L % bk == 0
     nk = L // bk
@@ -70,19 +77,20 @@ def decode_attention_kernel(q, k, v, bias, *, cap: Optional[float] = None,
                                cap=cap, nk=nk)
     return pl.pallas_call(
         kernel,
-        grid=(B, H, nk),
+        grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, j: (b, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk), lambda b, h, j: (b, j)),
+            pl.BlockSpec((None, None, g, hd), lambda b, h, j: (b, h, 0, 0)),
+            pl.BlockSpec((None, bk, hd), lambda b, h, j: (b, j, h)),
+            pl.BlockSpec((None, bk, hd), lambda b, h, j: (b, j, h)),
+            pl.BlockSpec((None, 1, bk), lambda b, h, j: (b, 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, j: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_specs=pl.BlockSpec((None, None, g, hd),
+                               lambda b, h, j: (b, h, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, hd), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, bias)

@@ -27,8 +27,7 @@ def decode_attention(q, k, v, bias, *, cap: Optional[float] = None,
     """q: [B,H,hd]; k/v: [B,L,KV,hd]; bias: [B,L] additive mask.
 
     ``interpret=None`` resolves backend-aware outside the jit boundary
-    (compiled on TPU, interpreter elsewhere; REPRO_PALLAS_INTERPRET
-    overrides per call)."""
+    (compiled on TPU, interpreter elsewhere)."""
     return _decode_attention(q, k, v, bias, cap=cap, bk=bk,
                              interpret=resolve_interpret(interpret))
 
@@ -48,6 +47,11 @@ def _decode_attention(q, k, v, bias, *, cap: Optional[float],
     kp = _pad_axis(k, 1, bk)
     vp = _pad_axis(v, 1, bk)
     biasp = _pad_axis(bias, 1, bk, value=NEG_INF)
-    out = decode_attention_kernel(q, kp, vp, biasp, cap=cap, bk=bk,
-                                  interpret=interpret)
-    return out[:, :, :hd]
+    # kernel layouts: q grouped by kv head, kv heads merged into the
+    # lane axis, bias with a unit row axis
+    KV, hdp, Lp = k.shape[2], q.shape[2], kp.shape[1]
+    out = decode_attention_kernel(
+        q.reshape(B, KV, H // KV, hdp), kp.reshape(B, Lp, KV * hdp),
+        vp.reshape(B, Lp, KV * hdp), biasp.reshape(B, 1, Lp), cap=cap,
+        bk=bk, interpret=interpret)
+    return out.reshape(B, H, hdp)[:, :, :hd]

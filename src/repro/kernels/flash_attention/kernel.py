@@ -5,10 +5,14 @@ running max / denominator / accumulator live in VMEM scratch and persist
 across kv blocks (the standard TPU flash pattern).  Supports causal
 masking, sliding windows, logit soft-capping and GQA (kv head = h // g).
 
-Block shapes are (1, bq, 1, hd) / (1, bk, 1, hd): hd is padded to a
-multiple of 128 by ops.py so the MXU matmul dims stay hardware-aligned,
-and bq/bk default to 128/512 to keep the working set
-(bq*hd + 2*bk*hd + bq*bk floats) inside VMEM (~16 MB/core on v5e).
+The kernel takes q as ``[B, Sp, H*hd]`` and k/v as ``[B, Sp, KV*hd]``
+(ops.py merges the head axes): a block is then one head's ``(bq, hd)``
+column tile, whose last two dims satisfy the TPU's (8, 128) tiling rule.
+On TPU the merge is a relayout copy, because the tiled layout of
+``[.., H, hd]`` tiles the head axis.  hd is padded to a multiple of 128 by ops.py so the MXU
+matmul dims stay hardware-aligned, and bq/bk default to 128/512 to keep
+the working set (bq*hd + 2*bk*hd + bq*bk floats) inside VMEM (~16 MB/core
+on v5e).
 """
 from __future__ import annotations
 
@@ -36,9 +40,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # [bq, hd]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # [bk, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
+    q = q_ref[...].astype(jnp.float32)                 # [bq, hd]
+    k = k_ref[...].astype(jnp.float32)                 # [bk, hd]
+    v = v_ref[...].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
     if cap is not None:
@@ -63,23 +67,23 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (
+        o_ref[...] = (
             acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
         ).astype(o_ref.dtype)
 
 
-def flash_attention_kernel(q, k, v, *, causal: bool = True,
+def flash_attention_kernel(q, k, v, *, n_heads: int, causal: bool = True,
                            window: Optional[int] = None,
                            cap: Optional[float] = None,
                            bq: int = 128, bk: int = 512,
                            seq_len: Optional[int] = None,
                            interpret: bool = True):
-    """q: [B, Sp, H, hd], k/v: [B, Sp, KV, hd] — Sp pre-padded to block
+    """q: [B, Sp, H*hd], k/v: [B, Sp, KV*hd] — Sp pre-padded to block
     multiples, hd padded to 128, by ops.py.  seq_len = true length."""
-    B, Sq, H, hd = q.shape
-    KV = k.shape[2]
+    B, Sq, Dq = q.shape
     Sk = k.shape[1]
-    g = H // KV
+    hd = Dq // n_heads
+    g = n_heads // (k.shape[2] // hd)
     bq = min(bq, Sq)
     bk = min(bk, Sk)
     assert Sq % bq == 0 and Sk % bk == 0
@@ -92,14 +96,14 @@ def flash_attention_kernel(q, k, v, *, causal: bool = True,
 
     return pl.pallas_call(
         kernel,
-        grid=(B, H, nq, nk),
+        grid=(B, n_heads, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, j: (b, j, h // g, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, i, j: (b, j, h // g, 0)),
+            pl.BlockSpec((None, bq, hd), lambda b, h, i, j: (b, i, h)),
+            pl.BlockSpec((None, bk, hd), lambda b, h, i, j: (b, j, h // g)),
+            pl.BlockSpec((None, bk, hd), lambda b, h, i, j: (b, j, h // g)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd),
-                               lambda b, h, i, j: (b, i, h, 0)),
+        out_specs=pl.BlockSpec((None, bq, hd),
+                               lambda b, h, i, j: (b, i, h)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),    # running max

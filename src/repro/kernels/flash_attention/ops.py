@@ -52,7 +52,11 @@ def _flash_attention(q, k, v, *, causal: bool, window: Optional[int],
     qp = _pad_axis(q, 1, bq if S > bq else S)
     kp = _pad_axis(k, 1, bk if Sk > bk else Sk)
     vp = _pad_axis(v, 1, bk if Sk > bk else Sk)
+    # merge the head axes: each kernel block is one head's (rows, hd)
+    # column tile
+    Sp, Skp, hdp = qp.shape[1], kp.shape[1], qp.shape[3]
     out = flash_attention_kernel(
-        qp, kp, vp, causal=causal, window=window, cap=cap, bq=bq, bk=bk,
-        seq_len=Sk, interpret=interpret)
-    return out[:, :S, :, :hd]
+        qp.reshape(B, Sp, H * hdp), kp.reshape(B, Skp, -1),
+        vp.reshape(B, Skp, -1), n_heads=H, causal=causal, window=window,
+        cap=cap, bq=bq, bk=bk, seq_len=Sk, interpret=interpret)
+    return out.reshape(B, Sp, H, hdp)[:, :S, :, :hd]

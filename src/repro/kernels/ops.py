@@ -1,7 +1,7 @@
 """Top-level kernel dispatch used by the model layer when
 `set_attention_impl("pallas")` is active.  Execution mode is
 backend-aware (``repro.kernels.backend``): compiled Pallas on TPU,
-interpret mode elsewhere, overridable via REPRO_PALLAS_INTERPRET."""
+interpret mode elsewhere."""
 from __future__ import annotations
 
 from typing import Optional

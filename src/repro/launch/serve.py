@@ -1,5 +1,5 @@
 """Serving launcher: continuous-batching generation for any --arch
-(smoke scale on CPU; the full-scale path is exercised via the dry-run).
+(``--smoke`` for the reduced config on CPU; published widths on a TPU).
 
 Requests stream through the genserve engine: at most --wave sequences
 decode concurrently, finished slots (EOS or budget) are recycled via
@@ -34,6 +34,7 @@ from repro.configs import archs
 from repro.core.plan import decode_wave, predicted_occupancy, prefill_rounds
 from repro.genserve import adapter as genserve
 from repro.genserve.adapter import ttft_quantiles
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.obs import metrics as obs_metrics
@@ -41,7 +42,7 @@ from repro.obs import trace as obs_trace
 from repro.rl.rollout import SamplerConfig
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -89,7 +90,12 @@ def main():
                          "(view in Perfetto / chrome://tracing)")
     ap.add_argument("--metrics", default=None, metavar="FILE",
                     help="dump the metrics-registry snapshot as JSON")
-    args = ap.parse_args()
+    return ap
+
+
+def run_serve(args):
+    """Serve ``args.batch`` requests through genserve and report; returns
+    the timed run's rollout dict and engine stats."""
     if args.trace:
         obs_trace.enable()
     if args.prefix_cache and args.page_size == 0:
@@ -209,6 +215,13 @@ def main():
             raise SystemExit("speculative greedy decode diverged from the "
                              "non-speculative engine (token parity check)")
         print("spec parity: speculative == non-speculative (greedy)")
+    return ro, stats
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    run_serve(args)
 
 
 if __name__ == "__main__":

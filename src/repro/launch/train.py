@@ -47,6 +47,7 @@ from repro.configs import archs
 from repro.configs.shapes import token_splits
 from repro.data.synthetic import random_lm_batch
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.obs import trace as obs_trace
@@ -124,25 +125,32 @@ def _assert_recovered(scenario: str, trainer, controller, injector) -> None:
               f"injected events")
 
 
-def run_rl(args) -> None:
-    """Scheduler search -> Plan -> engine-executed RL iterations."""
-    import numpy as np
+def run_rl(args):
+    """Scheduler search -> Plan -> engine-executed RL iterations.
 
+    Returns the trainer and one metrics dict per iteration (with the
+    iteration's wall seconds under ``iter_s``)."""
     from repro.core import topology, workflow
     from repro.core.plan import check_constraints
     from repro.core.sha import HybridScheduler
     from repro.data.synthetic import AdditionTask, PromptDataset, VOCAB_SIZE
     from repro.rl.trainer import RLConfig, RLTrainer
 
-    import dataclasses
-
     cfg = archs.get(args.arch, smoke=args.smoke)
-    # RL on the verifiable addition task needs its small vocab; fp32 keeps
-    # the tiny-model smoke run numerically stable on CPU
-    cfg = dataclasses.replace(cfg, vocab_size=VOCAB_SIZE, dtype="float32")
+    # the addition task's ids 0..VOCAB_SIZE-1 are valid in any vocabulary
+    # at least that large; the model keeps its own vocabulary and dtype
+    if cfg.vocab_size < VOCAB_SIZE:
+        raise ValueError(f"{cfg.name}: vocab {cfg.vocab_size} < the "
+                         f"addition task's {VOCAB_SIZE} ids")
     task = AdditionTask(max_operand=9)
-    topo = topology.build_testbed(args.scenario,
-                                  counts={"A100": 4, "L4": 4})
+    if jax.default_backend() == "tpu":
+        # plan on the chips present, priced by their device_kind
+        topo = topology.build_local(jax.devices())
+    else:
+        topo = topology.build_testbed(args.scenario,
+                                      counts={"A100": 4, "L4": 4})
+    print(f"planning on {topo.n} x "
+          f"{'/'.join(sorted({d.spec.name for d in topo.devices}))}")
     spec = workflow.LLMSpec.from_model_config(cfg)
     wf = workflow.make_workflow(args.rl, spec,
                                 synchronous=not args.asynchronous,
@@ -208,6 +216,7 @@ def run_rl(args) -> None:
 
     ds = iter(PromptDataset(task, batch=args.batch, seed=1))
     key = jax.random.PRNGKey(42)
+    history = []
     for step in range(args.steps):
         prompts, answers = next(ds)
         key, k = jax.random.split(key)
@@ -223,9 +232,11 @@ def run_rl(args) -> None:
                   f"epoch={rec.epoch} "
                   f"new={rec.decision.new_cost * 1e3:.3f}ms/iter")
             m = trainer.iteration(prompts, answers, k)
+        m = dict(m, iter_s=time.time() - t0)
+        history.append(m)
         print(f"iter {step:4d} reward={m['reward_mean']:.3f} "
               f"kl={m['kl']:.3f} sync={m['sync_gb'] * 1e3:.1f}MB "
-              f"({time.time() - t0:.2f}s)")
+              f"({m['iter_s']:.2f}s)")
         if args.fault and step + 1 == warmup:
             # calibration warmup done: arm the divergence monitor and
             # calibrated deadlines — undeclared faults are detectable
@@ -274,9 +285,10 @@ def run_rl(args) -> None:
         print(f"calibrated ({c.n_samples} samples, global scale "
               f"{c.global_scale:.3g}): ratio {ccmp['ratio']:.2f}")
     print("done")
+    return trainer, history
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -290,7 +302,9 @@ def main():
     ap.add_argument("--async", dest="asynchronous", action="store_true",
                     help="one-step off-policy RL execution (with --rl)")
     ap.add_argument("--scenario", default="single_region",
-                    help="testbed scenario the scheduler plans against")
+                    help="GPU testbed scenario the scheduler plans "
+                         "against off the TPU (on a TPU it plans on the "
+                         "chips present)")
     ap.add_argument("--search-budget", type=int, default=120,
                     help="scheduler budget in cost-model evaluations")
     ap.add_argument("--drift", default=None,
@@ -323,7 +337,12 @@ def main():
     ap.add_argument("--trace", default=None, metavar="FILE",
                     help="write a Chrome-trace JSON of the run "
                          "(view in Perfetto / chrome://tracing)")
-    args = ap.parse_args()
+    return ap
+
+
+def main():
+    args = build_parser().parse_args()
+    enable_compile_cache()
     if args.trace:
         obs_trace.enable()
 

@@ -47,14 +47,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# Sharding-invariant PRNG: partitionable threefry makes random bits a
-# pure function of (key, position) regardless of how an array is
-# partitioned, so a sharded decode samples exactly the stream the
-# single-device reference samples (cross-mesh token parity).  Set once
-# at import — flipping it mid-process would fork the PRNG stream
-# between already-initialized and later-initialized state.
-jax.config.update("jax_threefry_partitionable", True)
-
 _local = threading.local()
 
 

@@ -72,14 +72,42 @@ class RLConfig:
     draft_arch: str = ""
 
 
-def default_plan(wf: workflow.RLWorkflow, n_devices: Optional[int] = None):
+def default_plan(wf: workflow.RLWorkflow, devices: Optional[list] = None):
     """Colocate-all plan over the host devices (what a plan-less trainer
     executes): one task group, every task on every device."""
-    n = n_devices or jax.device_count()
-    topo = topology.build_host(n)
+    devices = list(devices) if devices is not None else jax.devices()
+    n = len(devices)
+    topo = topology.build_local(devices)
     grouping = (tuple(range(wf.n_tasks)),)
     return topo, enum_mod.build_plan(topo, wf, grouping, [n],
                                      list(range(n)))
+
+
+def make_actor_step(cfg: ModelConfig, rl: RLConfig) -> Callable:
+    """The actor update, unjitted: ``(params, opt_state, batch,
+    gen_start) -> (params, opt_state, metrics)``, one clipped-surrogate
+    step on a rollout batch (``sequences``, ``logp_old``, ``advantages``,
+    ``mask``) followed by Adam."""
+    def actor_loss(params, batch, gen_start):
+        lp_new, out = rollout.sequence_logprobs(
+            params, cfg, batch["sequences"], gen_start)
+        pl = losses.ppo_policy_loss(
+            lp_new, batch["logp_old"], batch["advantages"],
+            batch["mask"], clip_eps=rl.clip_eps)
+        loss = pl["loss"] + out["aux_loss"]
+        if rl.entropy_coef:
+            logits = out["logits"][:, gen_start - 1:-1]
+            loss = loss - rl.entropy_coef * losses.entropy_bonus(
+                logits, batch["mask"])
+        return loss, pl
+
+    def actor_step(params, opt_state, batch, gen_start):
+        (loss, pl), grads = jax.value_and_grad(
+            actor_loss, has_aux=True)(params, batch, gen_start)
+        new_params, new_opt, om = adam.adam_update(
+            params, grads, opt_state, adam.AdamConfig(lr=rl.lr))
+        return new_params, new_opt, {**pl, "loss": loss, **om}
+    return actor_step
 
 
 class RLTrainer:
@@ -132,7 +160,7 @@ class RLTrainer:
                 f"plan covers tasks {sorted(plan.parallel)} but the "
                 f"{rl_cfg.algorithm} workflow has {self.wf.n_tasks} tasks")
         if plan is None:
-            host_topo, plan = default_plan(self.wf)
+            host_topo, plan = default_plan(self.wf, devices)
             topo = topo if topo is not None else host_topo
         # ``devices`` restricts the engine to a subset of the host's jax
         # devices (e.g. an unsharded single-device baseline next to a
@@ -192,27 +220,8 @@ class RLTrainer:
         self._ref_logp_raw = ref_logp
         self._ref_logp = jax.jit(ref_logp, static_argnames=("gen_start",))
 
-        def actor_loss(params, batch, gen_start):
-            lp_new, out = rollout.sequence_logprobs(
-                params, cfg, batch["sequences"], gen_start)
-            pl = losses.ppo_policy_loss(
-                lp_new, batch["logp_old"], batch["advantages"],
-                batch["mask"], clip_eps=rl.clip_eps)
-            loss = pl["loss"] + out["aux_loss"]
-            if rl.entropy_coef:
-                logits = out["logits"][:, gen_start - 1:-1]
-                loss = loss - rl.entropy_coef * losses.entropy_bonus(
-                    logits, batch["mask"])
-            return loss, pl
-
-        def actor_step(params, opt_state, batch, gen_start):
-            (loss, pl), grads = jax.value_and_grad(
-                actor_loss, has_aux=True)(params, batch, gen_start)
-            new_params, new_opt, om = adam.adam_update(
-                params, grads, opt_state, adam.AdamConfig(lr=rl.lr))
-            return new_params, new_opt, {**pl, "loss": loss, **om}
-        self._actor_step_raw = actor_step
-        self._actor_step = jax.jit(actor_step,
+        self._actor_step_raw = make_actor_step(cfg, rl)
+        self._actor_step = jax.jit(self._actor_step_raw,
                                    static_argnames=("gen_start",))
 
         if rl.algorithm == "ppo":

@@ -250,3 +250,34 @@ def test_online_redeployment():
     assert d.plan is not None
     ok, _ = check_constraints(topo2, wf, d.plan)
     assert ok
+
+
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_local_topology_prices_chips_by_device_kind():
+    topo = topology.build_local([_Dev("tpu", "TPU v5 lite")] * 4)
+    assert topo.n == 4
+    assert all(d.spec is topology.TPU_V5E for d in topo.devices)
+    assert topo.locality(0, 3) == 3               # one machine
+    cpu = topology.build_local([_Dev("cpu", "cpu")] * 2)
+    assert all(d.spec is topology.A100 for d in cpu.devices)
+    with pytest.raises(ValueError, match="no spec for device kind"):
+        topology.build_local([_Dev("tpu", "TPU v99")])
+
+
+def test_gen_rest_plan_disjoint_groups():
+    wf = workflow.make_grpo(workflow.QWEN_1_7B, global_batch=8)
+    topo = topology.build_host(4, topology.TPU_V5E)
+    kinds = workflow.TaskKind
+    plan = enum_mod.gen_rest_plan(
+        topo, wf, [0, 1], [2, 3],
+        {kinds.GEN: (1, 1, 2), kinds.INF: (1, 1, 2), kinds.TRAIN: (1, 1, 2)})
+    gen = next(t for t in range(wf.n_tasks) if wf.task(t).kind == kinds.GEN)
+    assert plan.group_of(gen).devices == (0, 1)
+    for t in range(wf.n_tasks):
+        assert plan.parallel[t] == (1, 1, 2)
+        want = [0, 1] if t == gen else [2, 3]
+        assert plan.assignment[t].reshape(-1).tolist() == want
