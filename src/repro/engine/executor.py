@@ -335,14 +335,14 @@ class Engine:
                 task = self.wf.task(t)
                 fn = tasks_mod.executor_for(task)
                 devs = [int(d) for d in self.plan.assignment[t].reshape(-1)]
-                pl = self.placements[t]
-                mesh_attr = "x".join(str(s) for s in pl.mesh_shape) + "@" \
-                    + ",".join(str(getattr(d, "id", d))
-                               for d in pl.local_devices)
                 with obs_trace.span(f"task.{task.name}", task=t,
                                     iteration=self._iter,
-                                    epoch=self.ctx.epoch,
-                                    mesh=mesh_attr) as sp:
+                                    epoch=self.ctx.epoch) as sp:
+                    if obs_trace.is_enabled():
+                        pl = self.placements[t]
+                        sp.set("mesh", "x".join(map(str, pl.mesh_shape))
+                               + "@" + ",".join(str(getattr(d, "id", d))
+                                                for d in pl.local_devices))
                     t0 = time.monotonic()
                     try:
                         retry_mod.retry_call(
@@ -492,20 +492,20 @@ class Engine:
 
     # -- one iteration --------------------------------------------------
     def run_iteration(self, prompts, answers, rng) -> EngineResult:
-        t_iter0 = time.monotonic()
-        if self.fault_injector is not None:
-            self.fault_injector.begin_iteration(self._iter)
         with obs_trace.span("engine.iteration", iteration=self._iter,
                             epoch=self.ctx.epoch):
+            t_iter0 = time.monotonic()
+            if self.fault_injector is not None:
+                self.fault_injector.begin_iteration(self._iter)
             result = self._run_iteration(prompts, answers, rng)
-        obs_metrics.histogram("engine.iter_wall_s").observe(
-            time.monotonic() - t_iter0)
-        obs_metrics.gauge("engine.plan_epoch").set(self.ctx.epoch)
-        if self.pipeline.records:
-            rec = self.pipeline.records[-1]
-            obs_metrics.gauge("engine.staleness").set(
-                rec.weight_version - rec.gen_version)
-        self._observe_divergence(result)
+            obs_metrics.histogram("engine.iter_wall_s").observe(
+                time.monotonic() - t_iter0)
+            obs_metrics.gauge("engine.plan_epoch").set(self.ctx.epoch)
+            if self.pipeline.records:
+                rec = self.pipeline.records[-1]
+                obs_metrics.gauge("engine.staleness").set(
+                    rec.weight_version - rec.gen_version)
+            self._observe_divergence(result)
         return result
 
     def overlap_active(self) -> bool:

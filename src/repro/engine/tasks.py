@@ -22,6 +22,7 @@ import numpy as np
 from repro.core import plan as plan_mod
 from repro.core.workflow import Task, TaskKind
 from repro.genserve import adapter as genserve
+from repro.obs import trace as obs_trace
 from repro.rl import gae, losses
 from repro.rl import rewards as rewards_mod
 
@@ -204,44 +205,45 @@ def ensure_train_batch(st, bb):
     with bb["lock"]:
         if "batch" in bb:
             return
-        rl = st.rl
-        b = bb["bundle"]
-        ro = b["rollout"]
-        # under multi-device execution the rollout, reference and critic
-        # tensors are committed to different meshes; eager ops refuse to
-        # mix them, so the (tiny) advantage prep runs from host copies —
-        # the training executors re-commit the batch onto their meshes
-        multidev = bool(bb.get("multidev"))
-        # round-trip through host memory drops the device commitment
-        # while keeping jnp semantics downstream
-        pull = (lambda x: jnp.asarray(np.asarray(x))) if multidev \
-            else (lambda x: x)
-        mask = pull(ro["mask"])
-        lp_old = pull(ro["logprobs"])
-        lp_ref = pull(bb["lp_ref"])
-        tok_rewards, kl = losses.kl_penalised_rewards(
-            jnp.asarray(bb["scores"]), lp_old, lp_ref, mask,
-            kl_beta=rl.kl_beta)
-        bb["metrics"].update({
-            "reward_mean": float(bb["scores"].mean()),
-            "kl": float(kl),
-            "gen_len": float(np.asarray(mask).sum(1).mean()),
-        })
-        if rl.algorithm == "ppo":
-            values = pull(bb["values"])
-            adv, returns = gae.gae_advantages(
-                tok_rewards, values * mask, mask,
-                gamma=rl.gamma, lam=rl.lam)
-            bb["returns"] = returns
-        else:
-            seq_reward = np.asarray(tok_rewards).sum(1)
-            adv = gae.grpo_advantages(jnp.asarray(seq_reward),
-                                      rl.n_rollouts, mask)
-        if rl.whiten_advantages:
-            adv = gae.whiten(adv, mask)
-        bb["batch"] = {"sequences": pull(ro["sequences"]),
-                       "logp_old": lp_old,
-                       "advantages": adv, "mask": mask}
+        with obs_trace.span("rl.advantages"):
+            rl = st.rl
+            b = bb["bundle"]
+            ro = b["rollout"]
+            # under multi-device execution the rollout, reference and critic
+            # tensors are committed to different meshes; eager ops refuse to
+            # mix them, so the (tiny) advantage prep runs from host copies —
+            # the training executors re-commit the batch onto their meshes
+            multidev = bool(bb.get("multidev"))
+            # round-trip through host memory drops the device commitment
+            # while keeping jnp semantics downstream
+            pull = (lambda x: jnp.asarray(np.asarray(x))) if multidev \
+                else (lambda x: x)
+            mask = pull(ro["mask"])
+            lp_old = pull(ro["logprobs"])
+            lp_ref = pull(bb["lp_ref"])
+            tok_rewards, kl = losses.kl_penalised_rewards(
+                jnp.asarray(bb["scores"]), lp_old, lp_ref, mask,
+                kl_beta=rl.kl_beta)
+            bb["metrics"].update({
+                "reward_mean": float(bb["scores"].mean()),
+                "kl": float(kl),
+                "gen_len": float(np.asarray(mask).sum(1).mean()),
+            })
+            if rl.algorithm == "ppo":
+                values = pull(bb["values"])
+                adv, returns = gae.gae_advantages(
+                    tok_rewards, values * mask, mask,
+                    gamma=rl.gamma, lam=rl.lam)
+                bb["returns"] = returns
+            else:
+                seq_reward = np.asarray(tok_rewards).sum(1)
+                adv = gae.grpo_advantages(jnp.asarray(seq_reward),
+                                          rl.n_rollouts, mask)
+            if rl.whiten_advantages:
+                adv = gae.whiten(adv, mask)
+            bb["batch"] = {"sequences": pull(ro["sequences"]),
+                           "logp_old": lp_old,
+                           "advantages": adv, "mask": mask}
 
 
 @register(TaskKind.TRAIN, "actor_training")

@@ -7,9 +7,12 @@ module level), cheap enough to leave on in production smokes:
     Structured span tracer.  ``with trace.span("gen.round", wave=i):``
     records a nestable, exception-safe span on the current thread;
     spans export as Chrome-trace/Perfetto JSON (``export_chrome``) or a
-    human summary table (``report``).  Disabled spans cost one attribute
-    read.  ``REPRO_TRACE=<path>`` enables tracing at import and dumps
-    the trace at interpreter exit.
+    human summary table (``report``).  Enabled spans are also
+    ``jax.profiler.TraceAnnotation``s under their names, so a JAX
+    profiler trace (``.xplane.pb``) holds them as host events beside the
+    device's operations.  Disabled spans cost one attribute read.
+    ``REPRO_TRACE=<path>`` enables tracing at import and dumps the trace
+    at interpreter exit.
 
 ``repro.obs.metrics``
     Process-wide registry of counters / gauges / histograms replacing
@@ -28,11 +31,13 @@ module level), cheap enough to leave on in production smokes:
 
 Span naming scheme (dotted, category = first segment):
 
-    engine.iteration       one Engine.run_iteration call
+    engine.iteration       one Engine.run_iteration call, all of it
     engine.stage           one workflow stage dispatch
     task.<name>            one task executor (task.generation, ...)
     engine.sync            post-train weight sync to the GEN replica
     engine.swap            Engine.apply_plan plan transition
+    rl.advantages          KL-penalised rewards, advantages and their
+                           host reads, once per trained iteration
     gen.round              one genserve host round (admit+decode)
     gen.admit              one-shot admission program
     gen.install            chunked-admission install (+ prefix match)
@@ -41,7 +46,7 @@ Span naming scheme (dotted, category = first segment):
     elastic.poll           ElasticController drift reaction
     elastic.reschedule     scheduler warm-start search
     elastic.checkpoint     pre-swap checkpoint write
-    train.step             one RLTrainer.train_step
+    train.step             one step of the LM launcher's training loop
 
 Metric naming scheme (dotted namespaces):
 

@@ -11,7 +11,12 @@ When tracing is disabled (the default) ``span`` returns a shared no-op
 span: the cost is one attribute read and one method call, so
 instrumentation can stay unconditionally in hot host loops.  Enabled
 spans cost two ``perf_counter_ns`` reads and two list appends on a
-per-thread buffer (no lock on the hot path).
+per-thread buffer (no lock on the hot path), and each is also a
+``jax.profiler.TraceAnnotation`` under the span's name (arguments stay
+in the Chrome export): while a JAX profiler session runs, the span is a
+host event in its ``.xplane.pb``, on the profiler's clock and the thread
+that ran it, so device idle time can be put down to it.  JAX is imported
+on the first enabled span, not with this module.
 
 Export formats:
 
@@ -67,7 +72,7 @@ class Span:
     """One live span: a context manager recording [t0, t1) on exit."""
 
     __slots__ = ("name", "args", "id", "parent_id", "_tracer", "_buf",
-                 "t0_ns", "t1_ns", "seq_b")
+                 "_annotation", "t0_ns", "t1_ns", "seq_b")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self.name = name
@@ -83,12 +88,15 @@ class Span:
         self.args[key] = value
 
     def __enter__(self) -> "Span":
+        from jax.profiler import TraceAnnotation
         tr = self._tracer
         buf = tr._thread_buffer()
         stack = buf.stack
         self.parent_id = stack[-1].id if stack else 0
         stack.append(self)
         self._buf = buf
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.seq_b = next(tr._seq)
         self.t0_ns = time.perf_counter_ns()
         return self
@@ -99,11 +107,13 @@ class Span:
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         buf = self._buf
-        # pop self even if a child span leaked (exception unwound past it)
+        # pop self even if a child span leaked (exception unwound past
+        # it), closing the leaked child's annotation inside this one
         while buf.stack and buf.stack[-1] is not self:
-            buf.stack.pop()
+            buf.stack.pop()._annotation.__exit__(None, None, None)
         if buf.stack:
             buf.stack.pop()
+        self._annotation.__exit__(None, None, None)
         buf.records.append((self.name, self.id, self.parent_id,
                             self.t0_ns, self.t1_ns, self.seq_b, seq_e,
                             self.args))

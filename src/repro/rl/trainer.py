@@ -28,7 +28,6 @@ from repro.data.synthetic import AdditionTask, EOS
 from repro.engine.executor import Engine
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
-from repro.obs import trace as obs_trace
 from repro.optim import adam
 from repro.parallel import sharding as sh
 from repro.rl import gae, losses, rewards as rewards_mod, rollout
@@ -426,9 +425,7 @@ class RLTrainer:
         barrier).  Asynchronous: generate with the PREVIOUS sync's weights
         while training on the PREVIOUS iteration's rollouts (one-step
         off-policy); the first call only produces rollouts."""
-        with obs_trace.span("train.step", batch=int(prompts.shape[0])):
-            res = self.engine.run_iteration(prompts, answers, rng)
-        return res.metrics
+        return self.engine.run_iteration(prompts, answers, rng).metrics
 
     # ------------------------------------------------------------------
     def evaluate(self, prompts: np.ndarray, answers: np.ndarray,

@@ -124,6 +124,63 @@ def test_disabled_tracer_is_noop():
     assert tr.current_span_id() == 0
 
 
+def test_enabled_span_is_a_host_event_in_the_profiler_trace(tmp_path):
+    """An enabled span is a profiler annotation under its name alone (no
+    arguments), on the profiler's clock: it lands in the ``.xplane.pb``
+    as a host event that holds its children and the work inside it."""
+    import glob
+    from jax.profiler import ProfileData
+    obs_trace.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("m.outer", n=1):
+            with obs_trace.span("m.inner", k="v"):
+                jax.block_until_ready(jax.numpy.arange(8.0) * 2)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                           "*.xplane.pb"))
+    host = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    host.setdefault(e.name, []).append(
+                        (e.start_ns, e.start_ns + e.duration_ns))
+    assert len(host.get("m.outer", [])) == 1, sorted(host)
+    assert len(host.get("m.inner", [])) == 1, sorted(host)
+    assert not [n for n in host if n.startswith("m.") and "#" in n]
+    (a, b), = host["m.outer"]
+    (c, d), = host["m.inner"]
+    assert a <= c < d <= b
+    # the span's record keeps its arguments for the Chrome export
+    begins = [e for e in obs_trace.TRACER.chrome_events()
+              if e["ph"] == "B"]
+    assert {e["name"]: e["args"].get("k") for e in begins} == {
+        "m.outer": None, "m.inner": "v"}
+
+
+def test_disabled_span_builds_no_annotation(monkeypatch):
+    built = []
+
+    class Counted(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counted)
+    assert not obs_trace.is_enabled()
+    sp = obs_trace.span("m.off", a=1)
+    with sp:
+        pass
+    assert sp is obs_trace._NULL_SPAN
+    assert built == []
+    obs_trace.enable()
+    with obs_trace.span("m.on", a=1):
+        pass
+    assert built == ["m.on"]
+
+
 def test_module_singleton_env_flag_roundtrip(tmp_path):
     obs_trace.enable()
     assert obs_trace.is_enabled()
@@ -520,7 +577,7 @@ def test_e2e_trace_covers_engine_waves_and_swap(tmp_path):
     with open(path) as f:
         events = json.load(f)["traceEvents"]
     names = {e["name"] for e in events}
-    assert {"train.step", "engine.iteration", "engine.stage",
+    assert {"rl.advantages", "engine.iteration", "engine.stage",
             "engine.sync", "engine.swap",
             "task.actor_generation"} <= names
     assert any(n.startswith("gen.") for n in names), names
