@@ -1,6 +1,8 @@
 """Decode-time state: KV caches (full + ring-buffer windowed), SSM states,
 RWKV states.  Cache leaves for the scanned layer stack carry a leading
-[R] repeats dim so decode can scan over blocks with per-repeat cache slices.
+[R] repeats dim; decode carries the attention k/v stacks through its
+layer scan whole and writes each token in place (``write_token_kv``),
+while recurrent state goes through the scan per repeat.
 
 Every block-cache leaf is laid out ``[R, B, ...]`` with the batch (decode
 *slot*) dim at axis 1; ``gather_slots`` / ``scatter_slots`` exploit this
@@ -20,8 +22,8 @@ gather (batched-jnp attention, the Pallas flash-decode kernel, ring
 windows, GQA) is untouched — and ``paged_update_decode`` /
 ``paged_update_chunk`` scatter just the freshly written token k/v back
 into the pool (an exact delta: values are extracted from the contiguous
-view *after* ``write_kv`` applied its ring/clamp semantics, so a paged
-run is token-for-token the contiguous run).  Recurrent (Mamba/RWKV)
+view *after* the decode or chunk write applied its ring/clamp semantics,
+so a paged run is token-for-token the contiguous run).  Recurrent (Mamba/RWKV)
 state and ``cm_shift`` stay per-slot ``[R, B, ...]``: O(1) state has no
 pages to share.  An identity block table (``identity_block_table``) makes
 the pool a mere reshape of today's contiguous layout — the exact-parity
@@ -145,6 +147,49 @@ def ring_slot_positions(cache_len: int, window: Optional[int], pos):
     return k_pos, valid
 
 
+def token_slot(pos, cache_len: int, window: Optional[int]):
+    """Cache slot a single token at `pos` (scalar or [B]) is written to:
+    ring caches wrap at pos % L, full caches clamp to the last slot."""
+    if window is not None:
+        return pos % cache_len
+    return jnp.minimum(pos, cache_len - 1)
+
+
+def write_token_kv(cache_k, cache_v, layer, k_new, v_new, pos,
+                   window: Optional[int]):
+    """Write one decode token's k/v into layer `layer` of the stacked
+    caches, in place.
+
+    cache_k: [R, B, L, KV, hd]; k_new: [B, 1, KV, hd]; `layer` is the
+    (traced) repeat index.  Only the [B, 1, KV, hd] token is written —
+    no per-layer [B, L, KV, hd] slice is copied out or back — at the
+    slot ``write_kv`` would use: a scalar `pos` is one
+    dynamic-update-slice, a [B] `pos` a per-row scatter.
+
+    The write goes through the [R, B, KV, L, hd] view of the stack.
+    Written in the [R, B, L, KV, hd] view, the token (laid out as the
+    k/v projection leaves it) pulls the whole stack into an (L, KV, B)
+    layout, and the TPU compiler then copies every layer's slice into
+    the layout attention reads; through this view the stack keeps
+    attention's layout and attention reads it in place."""
+    L = cache_k.shape[2]
+    p = jnp.asarray(pos, jnp.int32)
+    slot = token_slot(p, L, window)
+
+    def put(cache, new):
+        view = jnp.swapaxes(cache, 2, 3)                 # [R, B, KV, L, hd]
+        new = jnp.swapaxes(new, 1, 2).astype(cache.dtype)  # [B, KV, 1, hd]
+        if p.ndim == 0:
+            view = jax.lax.dynamic_update_slice(view, new[None],
+                                                (layer, 0, 0, slot, 0))
+        else:
+            rows = jnp.arange(new.shape[0])
+            view = view.at[layer, rows, :, slot].set(new[:, :, 0])
+        return jnp.swapaxes(view, 2, 3)
+
+    return put(cache_k, k_new), put(cache_v, v_new)
+
+
 def write_kv(cache_k, cache_v, k_new, v_new, pos, window: Optional[int],
              valid=None):
     """Write a chunk of Sq consecutive tokens' k/v starting at `pos`.
@@ -168,14 +213,6 @@ def write_kv(cache_k, cache_v, k_new, v_new, pos, window: Optional[int],
     B, C = k_new.shape[:2]
     L = cache_k.shape[1]
     p = jnp.asarray(pos)
-    if p.ndim == 0 and C == 1 and valid is None:
-        # scalar single-token decode fast path
-        slot = p % L if window is not None else jnp.minimum(p, L - 1)
-        cache_k = jax.lax.dynamic_update_slice(
-            cache_k, k_new.astype(cache_k.dtype), (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(
-            cache_v, v_new.astype(cache_v.dtype), (0, slot, 0, 0))
-        return cache_k, cache_v
     if p.ndim == 0 and valid is None:
         # statically-known scalar start (the one-shot prefill and any
         # within-capacity chunk): a contiguous slice update beats the
@@ -422,8 +459,9 @@ def paged_update_decode(cfg: ModelConfig, blocks, new_blocks, btab, pos,
 
     ``new_blocks`` is the contiguous view *after* ``decode_step`` ran on
     it; ``pos`` is the [B] pre-increment per-slot position the token was
-    written at (ring slot pos % L, full-cache slot min(pos, L-1) — the
-    same mapping ``write_kv`` used, so the extracted value is exact).
+    written at (``token_slot``: ring slot pos % L, full-cache slot
+    min(pos, L-1) — the mapping decode wrote with, so the extracted
+    value is exact).
     Rows where ``emit`` is False write nothing: a freed page can never
     be corrupted by a retired slot's stale block-table row."""
     B = btab.shape[0]
@@ -438,8 +476,7 @@ def paged_update_decode(cfg: ModelConfig, blocks, new_blocks, btab, pos,
             out[name] = _merge_per_slot(layer, new, emit)
             continue
         L = kv_cache_len(cfg, spec, max_seq, long_mode)
-        w = effective_window(cfg, spec, long_mode)
-        slot = p % L if w is not None else jnp.minimum(p, L - 1)
+        slot = token_slot(p, L, effective_window(cfg, spec, long_mode))
         n_pool = layer["k"].shape[1]
         page = btab[rows, slot // page_size]
         page = jnp.where(emit, page, n_pool)   # masked rows -> dropped

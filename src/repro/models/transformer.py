@@ -405,9 +405,27 @@ def verify_chunk_step(params, cfg: ModelConfig, tokens, cache, *,
 # Decode (single token, serve_step)
 # ---------------------------------------------------------------------------
 
-def _layer_decode(p, cfg: ModelConfig, spec, x, cache_entry, pos, *,
+def _split_entry(spec, entry):
+    """(carried, scanned) leaves of one layer's decode cache entry: the
+    layer scan carries attention k/v whole ([R, B, L, KV, hd], written
+    one token in place); every other leaf is whole-state recurrence and
+    goes through the scan per repeat."""
+    carried, scanned = {}, {}
+    for key, leaf in entry.items():
+        kv = spec.mixer == Mixer.ATTENTION and key in ("k", "v")
+        (carried if kv else scanned)[key] = leaf
+    return carried, scanned
+
+
+def _layer_decode(p, cfg: ModelConfig, spec, x, cache_entry, pos, layer, *,
                   long_mode):
     """x: [B,1,d]. Returns (x, new_cache_entry).
+
+    `cache_entry` holds this layer's recurrent leaves as per-repeat
+    slices and, for attention, the k/v stacks of every repeat
+    ([R, B, L, KV, hd]); `layer` is the repeat index into them.  The
+    token's k/v are written into the stacks in place and attention reads
+    repeat `layer` of the written stacks.
 
     `pos` is a scalar (all rows decode the same position — the
     single-sequence / full-batch rollout path) or a [B] vector of
@@ -421,13 +439,15 @@ def _layer_decode(p, cfg: ModelConfig, spec, x, cache_entry, pos, *,
     if spec.mixer == Mixer.ATTENTION:
         window = cache_mod.effective_window(cfg, spec, long_mode)
         q, k, v = attn_mod.qkv_project(p["attn"], cfg, h, q_pos)
-        ck, cv = cache_mod.write_kv(cache_entry["k"], cache_entry["v"],
-                                    k, v, pos, window)
+        ck, cv = cache_mod.write_token_kv(cache_entry["k"], cache_entry["v"],
+                                          layer, k, v, pos, window)
         new_entry.update(k=ck, v=cv)
-        L = ck.shape[1]
+        L = ck.shape[2]
         k_pos, valid = cache_mod.ring_slot_positions(L, window, pos)
         y = attn_mod.multihead_attention(
-            q, ck, cv, q_pos, k_pos,
+            q, jax.lax.dynamic_index_in_dim(ck, layer, keepdims=False),
+            jax.lax.dynamic_index_in_dim(cv, layer, keepdims=False),
+            q_pos, k_pos,
             causal=True, window=window, cap=cfg.attn_softcap, k_valid=valid)
         y = y.reshape(x.shape[0], 1, -1) @ p["attn"]["wo"]
     elif spec.mixer == Mixer.MAMBA:
@@ -463,25 +483,38 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, *,
     [B] vector of per-row positions — the natively batched fast path the
     genserve wave decode uses: ragged KV lengths, RoPE phases and
     ring-window validity are expressed per-row, so one fused attention
-    call covers a whole wave of recycled slots."""
+    call covers a whole wave of recycled slots.
+
+    The layer scan carries the attention k/v stacks ([R, B, L, KV, hd])
+    and writes each layer's token into them in place, so no layer's
+    cache is sliced out and stacked back each step; recurrent state is
+    scanned per repeat."""
     assert not cfg.is_encoder_only, "encoder-only models have no decode step"
     pos = cache["pos"]
     x = embed_tokens(params["embed"], cfg, tokens)
     x = hint(x, "decode_residual")
 
-    def scan_body(x, inp):
-        bp, centry = inp
-        new_entries = {}
-        for j, spec in enumerate(cfg.pattern):
-            x, ne = _layer_decode(bp[f"layer{j}"], cfg, spec, x,
-                                  centry[f"layer{j}"], pos,
-                                  long_mode=long_mode)
-            new_entries[f"layer{j}"] = ne
-        return x, new_entries
+    names = [f"layer{j}" for j in range(len(cfg.pattern))]
 
-    x, new_blocks = jax.lax.scan(scan_body, x,
-                                 (params["blocks"], cache["blocks"]))
+    def scan_body(carry, inp):
+        x, kv = carry
+        bp, state, r = inp
+        kv, new_state = dict(kv), {}
+        for name, spec in zip(names, cfg.pattern):
+            x, ne = _layer_decode(bp[name], cfg, spec, x,
+                                  {**kv[name], **state[name]}, pos, r,
+                                  long_mode=long_mode)
+            kv[name], new_state[name] = _split_entry(spec, ne)
+        return (x, kv), new_state
+
+    kv, state = {}, {}
+    for name, spec in zip(names, cfg.pattern):
+        kv[name], state[name] = _split_entry(spec, cache["blocks"][name])
+    (x, kv), state = jax.lax.scan(
+        scan_body, (x, kv),
+        (params["blocks"], state, jnp.arange(cfg.n_pattern_repeats)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["embed"], cfg, x)[:, 0]
+    new_blocks = {name: {**kv[name], **state[name]} for name in kv}
     new_cache = {"blocks": new_blocks, "pos": pos + 1}
     return logits, new_cache

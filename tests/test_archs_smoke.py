@@ -83,9 +83,20 @@ DECODE_ARCHS = [n for n in ALL_ARCHS
                 and archs.get(n, smoke=True).frontend == "tokens"]
 
 
-@pytest.mark.parametrize("name", DECODE_ARCHS)
-def test_decode_matches_forward(name):
+# cases at 4 layers: two repeats of a two-layer pattern, so the decode
+# layer scan writes and reads the carried k/v stacks at a repeat index
+# above 0 — gemma2 with its ring-window layer wrapping (pos > 16) beside
+# a full-length layer, jamba with attention beside Mamba state
+STACKED_DECODE = [("gemma2-27b", 4), ("jamba-1.5-large-398b", 4)]
+
+
+@pytest.mark.parametrize("name,n_layers", [
+    *[pytest.param(n, None, id=n) for n in DECODE_ARCHS],
+    *[pytest.param(n, k, id=f"{n}-{k}layers") for n, k in STACKED_DECODE]])
+def test_decode_matches_forward(name, n_layers):
     cfg = archs.get(name, smoke=True)
+    if n_layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
     if cfg.has_moe:
         cfg = dataclasses.replace(cfg, capacity_factor=8.0)  # avoid drops
     params = T.init_params(KEY, cfg)

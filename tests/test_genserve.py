@@ -14,6 +14,8 @@
 - engine integration: the TaskKind.GEN executor produces per-wave Event
   timeline entries comparable against the cost model's decode_wave.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,7 +27,7 @@ from repro.genserve import adapter as genserve
 from repro.genserve.decoder import GenServeConfig, serve
 from repro.genserve.scheduler import FREE, Request, RequestQueue, SlotTable
 from repro.models import transformer as T
-from repro.models.config import LayerSpec, ModelConfig
+from repro.models.config import LayerSpec, Mixer, ModelConfig
 from repro.rl import rollout
 from repro.rl.trainer import RLConfig, RLTrainer
 
@@ -38,6 +40,13 @@ def tiny_cfg(window=None):
                        n_heads=2, n_kv_heads=2, head_dim=32, d_ff=128,
                        vocab_size=VOCAB_SIZE, dtype="float32",
                        pattern=(LayerSpec(window=window),))
+
+
+def hybrid_cfg(window=4):
+    """Two repeats of a ring-window attention layer beside a Mamba layer."""
+    return dataclasses.replace(
+        tiny_cfg(window), name=f"gs-hybrid-w{window}", n_layers=4,
+        pattern=(LayerSpec(window=window), LayerSpec(mixer=Mixer.MAMBA)))
 
 
 @pytest.fixture(scope="module")
@@ -183,13 +192,15 @@ def test_uniform_occupancy_matches_decode_wave(setup):
         float(plan_mod.decode_wave(B * W / B)))  # = W: full waves
 
 
-@pytest.mark.parametrize("window", [None, 4])
-def test_batched_decode_matches_vmapped_per_slot(window):
+@pytest.mark.parametrize("window,hybrid", [
+    pytest.param(None, False, id="None"), pytest.param(4, False, id="4"),
+    pytest.param(4, True, id="hybrid-mamba")])
+def test_batched_decode_matches_vmapped_per_slot(window, hybrid):
     """Tentpole parity: the batched wave decode (per-slot positions in
     one decode_step) is token-for-token identical to the legacy vmapped
-    per-slot path, including recycled slots at distinct positions and
-    ring windows."""
-    cfg = tiny_cfg(window=window)
+    per-slot path, including recycled slots at distinct positions, ring
+    windows and a hybrid attention + Mamba pattern."""
+    cfg = hybrid_cfg(window) if hybrid else tiny_cfg(window=window)
     params = T.init_params(KEY, cfg)
     prompts = prompts_for(14, key=5, cfg=cfg)
     kw = dict(wave=4, max_new_tokens=N, greedy=True, eos_token=3)

@@ -266,6 +266,64 @@ def test_prefill_kv_is_write_kv_chunk():
                                               np.asarray(v[:, p]))
 
 
+@pytest.mark.parametrize("window", [None, 4])
+@pytest.mark.parametrize("pos", [2, 9, [1, 6, 3]],
+                         ids=["scalar", "scalar_past_end", "per_row"])
+def test_write_token_kv_matches_write_kv(window, pos):
+    """The in-place stacked write leaves repeat `layer` exactly as
+    ``write_kv`` leaves that layer's own cache, and every other repeat
+    untouched — scalar and per-row positions, ring wrap and full-cache
+    clamp (pos 9 > L)."""
+    R, B, L, KV, hd, layer = 3, 3, 4, 2, 2, 1
+    ks = jax.random.split(KEY, 4)
+    ck = jax.random.normal(ks[0], (R, B, L, KV, hd))
+    cv = jax.random.normal(ks[1], (R, B, L, KV, hd))
+    k_new = jax.random.normal(ks[2], (B, 1, KV, hd))
+    v_new = jax.random.normal(ks[3], (B, 1, KV, hd))
+    pos = jnp.asarray(pos)
+    got_k, got_v = jax.jit(cache_mod.write_token_kv, static_argnums=6)(
+        ck, cv, jnp.asarray(layer), k_new, v_new, pos, window)
+    want_k, want_v = cache_mod.write_kv(ck[layer], cv[layer], k_new, v_new,
+                                        pos, window)
+    np.testing.assert_array_equal(np.asarray(got_k),
+                                  np.asarray(ck.at[layer].set(want_k)))
+    np.testing.assert_array_equal(np.asarray(got_v),
+                                  np.asarray(cv.at[layer].set(want_v)))
+
+
+@pytest.mark.parametrize("pos", [5, [3, 7]],
+                         ids=["scalar_pos", "per_row_pos"])
+def test_decode_layer_scan_carries_kv_cache(pos):
+    """decode_step's layer scan carries the [R, B, L, KV, hd] k/v stacks
+    and writes each token into them in place: no cache leaf enters the
+    scan as a per-layer slice (xs) or leaves it as a restacked per-layer
+    output (ys), which would copy every layer's cache twice a step."""
+    from repro.models import transformer as T
+    cfg = ModelConfig(name="scan-kv", n_layers=3, d_model=32, n_heads=4,
+                      n_kv_heads=2, head_dim=8, d_ff=64, vocab_size=64,
+                      dtype="float32")
+    B, L = 2, 12
+    params = T.init_params(KEY, cfg)
+    cache = cache_mod.init_cache(cfg, B, L, dtype=jnp.float32)
+    cache["pos"] = jnp.asarray(pos, jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda p, t, c: T.decode_step(p, cfg, t, c))(
+        params, jnp.zeros((B, 1), jnp.int32), cache).jaxpr
+    scans = [e for e in jaxpr.eqns if e.primitive.name == "scan"]
+    assert len(scans) == 1 and scans[0].params["length"] == 3
+    eqn = scans[0]
+    n_consts, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+
+    def shapes(vs):
+        return [tuple(v.aval.shape) for v in vs]
+
+    kv_shape = (3, B, L, cfg.n_kv_heads, cfg.resolved_head_dim)
+    carry = shapes(eqn.invars[n_consts:n_consts + n_carry])
+    xs = shapes(eqn.invars[n_consts + n_carry:])
+    ys = shapes(eqn.outvars[n_carry:])
+    assert carry.count(kv_shape) == 2          # the k and v stacks
+    assert not any(s[1:] == kv_shape[1:] for s in xs + ys), (xs, ys)
+
+
 @pytest.mark.parametrize("window,cap,KV", [
     (None, None, 2), (4, None, 2), (None, 30.0, 1), (6, 20.0, 4),
 ])

@@ -7,6 +7,7 @@ full-width qwen3-0.6b actor step from shapes alone; nothing runs.  The
 topology is described inside a fixture (never at import) so that only
 the worker running this file loads the TPU library.
 """
+import dataclasses
 import os
 
 import jax
@@ -18,6 +19,7 @@ from repro.configs import archs
 from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.models import transformer as T
+from repro.models.cache import init_cache
 from repro.optim import adam
 from repro.rl.trainer import RLConfig, make_actor_step
 
@@ -105,3 +107,38 @@ def test_qwen3_actor_step_fits_one_chip(one_chip):
         f"{mem.output_size_in_bytes / 1e9:.2f} GB + temp "
         f"{mem.temp_size_in_bytes / 1e9:.2f} GB + ref "
         f"{ref_bytes / 1e9:.2f} GB >= {HBM_BYTES / 1e9:.2f} GB")
+
+
+def test_qwen3_decode_step_writes_cache_in_place(one_chip):
+    """Compiled for the chip, decode_step's layer loop copies no layer's
+    [B, L, KV, hd] k or v: the token goes into the carried [R, B, L, KV,
+    hd] stack in place and attention reads the stack through a fused
+    slice (qwen3-0.6b's widths, two layers, the grpo-sync cell's 8 rows
+    of 64 + 128 positions)."""
+    import re
+    cfg = dataclasses.replace(archs.get("qwen3-0.6b"), n_layers=2)
+    B, L = 8, 192
+    on_chip = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+    params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: init_cache(cfg, B, L))
+    tokens = _shape(one_chip, (B, 1), jnp.int32)
+    hlo = jax.jit(lambda p, t, c: T.decode_step(p, cfg, t, c)).lower(
+        on_chip(params), tokens, on_chip(cache)).compile().as_text()
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            words = line.split()
+            cur = comps.setdefault(words[words[0] == "ENTRY"], [])
+        elif cur is not None:
+            cur.append(line)
+    bodies = re.findall(r"body=(%[\w.\-]+)", hlo)
+    assert bodies, "no layer loop in the compiled decode step"
+    kv = cfg.n_kv_heads
+    hd = cfg.resolved_head_dim
+    per_layer = re.compile(
+        rf"= bf16\[(1,)?{B},{L},{kv},{hd}\]|= bf16\[(1,)?{B},{kv},{L},{hd}\]")
+    copies = [l.strip()[:120] for b in bodies for l in comps.get(b, [])
+              if per_layer.search(l)]
+    assert not copies, copies
