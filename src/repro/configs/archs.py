@@ -1,4 +1,4 @@
-"""The 10 assigned architectures (exact full configs + reduced smoke
+"""The assigned architectures (exact full configs + reduced smoke
 variants).  Every entry cites its source in ``source``.
 """
 from __future__ import annotations
@@ -8,6 +8,7 @@ from repro.models.config import FFN, LayerSpec, Mixer, ModelConfig, reduced
 A = Mixer.ATTENTION
 M = Mixer.MAMBA
 R6 = Mixer.RWKV6
+SC = Mixer.SHORTCONV
 
 
 PHI3_MEDIUM_14B = ModelConfig(
@@ -114,12 +115,44 @@ GEMMA2_27B = ModelConfig(
     source="arXiv:2408.00118 (Gemma 2; local/global alternating + softcap)",
 )
 
+# LFM2-8B-A1B: gated short-conv and GQA layers in the published
+# ``layer_types`` order; layers 0-1 dense SwiGLU (``num_dense_layers``),
+# 2-23 with 32 sigmoid-routed experts, top-4, selection bias.  The config
+# gives no head_dim (64 = 2048 / 32) and no tie key (a tied head, as the
+# 8.3B total implies).
+LFM2_LAYER_TYPES = (
+    "conv", "conv", "full_attention", "conv", "conv", "conv",
+    "full_attention", "conv", "conv", "conv", "full_attention", "conv",
+    "conv", "conv", "full_attention", "conv", "conv", "conv",
+    "full_attention", "conv", "conv", "full_attention", "conv", "conv")
+
+
+def lfm2_pattern(layer_types, n_dense: int):
+    return tuple(LayerSpec(A if t == "full_attention" else SC, FFN.SWIGLU,
+                           moe=j >= n_dense)
+                 for j, t in enumerate(layer_types))
+
+
+LFM2_8B_A1B = ModelConfig(
+    name="lfm2-8b-a1b",
+    n_layers=24, d_model=2048, n_heads=32, n_kv_heads=8, head_dim=64,
+    d_ff=7168, vocab_size=65536,
+    pattern=lfm2_pattern(LFM2_LAYER_TYPES, 2),
+    n_experts=32, top_k=4, expert_d_ff=1792, router_aux_coef=0.0,
+    router_score="sigmoid", expert_bias=True, norm_topk=True,
+    routed_scale=1.0, conv_kernel=3,
+    qk_norm=True, rope=True, rope_theta=1_000_000.0, tie_embeddings=True,
+    embed_scale=1.0, norm_eps=1e-5,
+    source="https://huggingface.co/LiquidAI/LFM2-8B-A1B/blob/main/"
+           "config.json",
+)
+
 
 ARCHS = {
     c.name: c for c in [
         PHI3_MEDIUM_14B, GRANITE_MOE_3B, MIXTRAL_8X7B, QWEN3_0_6B,
         NEMOTRON_4_15B, HUBERT_XLARGE, JAMBA_1_5_LARGE, RWKV6_3B,
-        PIXTRAL_12B, GEMMA2_27B,
+        PIXTRAL_12B, GEMMA2_27B, LFM2_8B_A1B,
     ]
 }
 

@@ -134,9 +134,8 @@ def model_flops_per_layer(m: LLMSpec, seq: int) -> float:
         mlp = 2 * 2 * seq * m.h1 * m.h2 + 2 * seq * m.h1 * m.h1
         return proj + attn + mlp
     qkvo = 2 * 4 * seq * m.h1 * m.h1
-    attn = 2 * 2 * seq * seq * m.h1
-    mult = m.top_k if m.n_experts else 1
-    mlp = 2 * 3 * seq * m.h1 * m.h2 * mult
+    attn = 2 * 2 * seq * seq * m.h1 * m.attn_share
+    mlp = 2 * seq * (m.layer_active_count - 4 * m.h1 * m.h1)
     return qkvo + attn + mlp
 
 
@@ -338,7 +337,8 @@ class CostModel:
             fl += 2 * 2 * C * cache_len * m.h1     # cache cross-attention
             kv_dim = (m.n_kv_heads * m.head_dim
                       if m.n_kv_heads and m.head_dim else m.h1)
-            kv_tok, kv_len = 2.0 * kv_dim * BYTES_BF16, cache_len
+            kv_tok = 2.0 * kv_dim * BYTES_BF16 * m.attn_share
+            kv_len = cache_len
         worst = 0.0
         for k in range(tp):
             d = int(plan.assignment[t][i, j, k])
@@ -399,7 +399,7 @@ class CostModel:
             # h1 fallback when the spec has no head geometry
             kv_dim = (m.n_kv_heads * m.head_dim
                       if m.n_kv_heads and m.head_dim else m.h1)
-            kv_tok = 2.0 * kv_dim * BYTES_BF16
+            kv_tok = 2.0 * kv_dim * BYTES_BF16 * m.attn_share
             kv_len = self.wf.seq_in + self.wf.seq_out / 2.0
         worst = 0.0
         for k in range(tp):

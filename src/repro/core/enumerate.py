@@ -173,7 +173,8 @@ def default_parallelization(topo: Topology, wf: RLWorkflow, t: int,
     if task.kind == TaskKind.GEN:
         from repro.core.plan import MAX_DECODE_WAVE
         m = task.model
-        h_kv = (m.n_kv_heads * m.head_dim) if m.n_kv_heads else m.h1
+        h_kv = ((m.n_kv_heads * m.head_dim) if m.n_kv_heads else m.h1) \
+            * m.attn_share
         bytes_pp += 4 * m.n_layers * h_kv * (wf.seq_in + wf.seq_out) \
             * MAX_DECODE_WAVE
     mem_min = min(topo.mem(d) for d in devices)

@@ -214,6 +214,7 @@ def working_memory(wf: RLWorkflow, plan: Plan, t: int, i: int, j: int) -> float:
             h_kv = m.h1 // 8  # recurrent state, not seq-proportional
             seq_eff = 1
         else:
+            h_kv *= m.attn_share
             seq_eff = seq
         wave = decode_wave(local_batch)
         return 2 * BYTES_BF16 * nl_j * h_kv * seq_eff * wave / tp

@@ -35,13 +35,39 @@ class LLMSpec:
     n_heads: int = 0
     n_kv_heads: int = 0
     head_dim: int = 0
-    n_experts: int = 0      # 0 = dense
+    n_experts: int = 0      # 0 = dense; else the router's width
     top_k: int = 0
     attention_free: bool = False
+    # the layer mix and one device's share of the experts: layers with
+    # experts and with attention (-1 = all), the experts held here (0 =
+    # all), the expert width (0 = h2).  A layer without attention has a
+    # recurrent or short-conv mixer of about the same 4*h1^2 weights.
+    moe_layers: int = -1
+    attn_layers: int = -1
+    experts_held: int = 0
+    expert_h2: int = 0
+
+    @property
+    def moe_share(self) -> float:
+        """Share of the layers that hold experts."""
+        if not self.n_experts:
+            return 0.0
+        return 1.0 if self.moe_layers < 0 else self.moe_layers / self.n_layers
+
+    @property
+    def attn_share(self) -> float:
+        """Share of the layers with attention (and a KV cache)."""
+        if self.attention_free:
+            return 0.0
+        return 1.0 if self.attn_layers < 0 else self.attn_layers / self.n_layers
+
+    def _expert_weights(self, experts: float) -> float:
+        return 3.0 * self.h1 * (self.expert_h2 or self.h2) * experts
 
     @property
     def layer_weight_count(self) -> float:
-        """Weights per layer. Dense: the paper's 4*h1^2 + 3*h1*h2."""
+        """Mean weights per layer held here. Dense: the paper's
+        4*h1^2 + 3*h1*h2."""
         attn = 0.0 if self.attention_free else 4.0 * self.h1 * self.h1
         ffn = 3.0 * self.h1 * self.h2
         if self.attention_free:
@@ -49,18 +75,20 @@ class LLMSpec:
             attn = 5.0 * self.h1 * self.h1
             ffn = 2.0 * self.h1 * self.h2 + self.h1 * self.h1
         if self.n_experts:
-            ffn *= self.n_experts
+            ffn += self.moe_share * (self._expert_weights(
+                self.experts_held or self.n_experts) - ffn)
         return attn + ffn
 
     @property
     def layer_active_count(self) -> float:
-        """Weights touched per token (MoE: top_k experts only)."""
+        """Mean weights touched per token and layer here (MoE: the top_k
+        experts, of which a held/router-width share live here)."""
         if not self.n_experts:
             return self.layer_weight_count
-        dense = self.layer_weight_count
-        ffn_all = 3.0 * self.h1 * self.h2 * self.n_experts
-        ffn_act = 3.0 * self.h1 * self.h2 * self.top_k
-        return dense - ffn_all + ffn_act
+        held = self.experts_held or self.n_experts
+        return self.layer_weight_count - self.moe_share * (
+            self._expert_weights(held)
+            - self._expert_weights(self.top_k * held / self.n_experts))
 
     @property
     def total_weight_count(self) -> float:
@@ -69,12 +97,20 @@ class LLMSpec:
 
     @classmethod
     def from_model_config(cls, cfg: ModelConfig) -> "LLMSpec":
+        from repro.models.config import Mixer
+        attn = sum(s.mixer == Mixer.ATTENTION for s in cfg.pattern)
+        moe = cfg.has_moe
         return cls(
             name=cfg.name, n_layers=cfg.n_layers, h1=cfg.d_model,
             h2=cfg.d_ff, vocab=cfg.vocab_size, n_heads=cfg.n_heads,
             n_kv_heads=cfg.n_kv_heads, head_dim=cfg.resolved_head_dim,
-            n_experts=cfg.n_experts, top_k=cfg.top_k,
-            attention_free=cfg.attention_free)
+            n_experts=cfg.n_experts if moe else 0,
+            top_k=cfg.top_k if moe else 0,
+            attention_free=cfg.attention_free,
+            moe_layers=cfg.n_moe_layers if moe else -1,
+            attn_layers=attn * cfg.n_pattern_repeats,
+            experts_held=cfg.n_held if moe else 0,
+            expert_h2=cfg.resolved_expert_d_ff if moe else 0)
 
 
 # The paper's evaluated models (Qwen series, §5.1)

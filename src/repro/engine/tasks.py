@@ -131,6 +131,8 @@ def run_generation(st, bb, placement):
             # the single-wave path decodes all B rows at once — its wave
             # width is B, whatever the cost model's bound says
             stats = genserve.wave_stats_from_mask(ro["mask"])
+    if "moe_load" in ro:
+        st.count_moe_load("gen", ro["moe_load"])
     bb["gen_stats"] = stats
     bb["fresh"] = {"rollout": ro, "answers_rep": bb["answers_rep"],
                    "gen_start": bb["gen_start"],
@@ -262,6 +264,9 @@ def run_actor_training(st, bb, placement):
                 batch = jax.device_put(batch, placement.local_devices[0])
             st.actor, st.actor_opt, am = st._actor_step(
                 st.actor, st.actor_opt, batch, gen_start=b["gen_start"])
+    am = dict(am)
+    if "moe_load" in am:
+        st.count_moe_load("train", am.pop("moe_load"))
     with bb["lock"]:
         bb["metrics"].update({k: float(v) for k, v in am.items()})
     return st.actor

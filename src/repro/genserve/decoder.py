@@ -794,7 +794,17 @@ def serve(params, cfg: ModelConfig, prompts, rng, gcfg: GenServeConfig,
     program is traced under the mesh with ``parallel.sharding``
     activation hints active, so continuous-batching decode runs sharded
     over the generation group's devices.  The mesh joins the jit-program
-    cache key (constraints are baked in at trace time)."""
+    cache key (constraints are baked in at trace time).
+
+    Short-conv (LFM2) models are refused: the engine's caches and
+    chunked admission have no short-conv state.  The RL path generates
+    them through the single-wave ``rl.rollout.generate``."""
+    if cfg.has_shortconv:
+        raise NotImplementedError(
+            f"genserve does not run {cfg.name}: its short-conv layers' "
+            "state has no place in the engine's caches or chunked "
+            "admission; generate with repro.rl.rollout.generate (one "
+            "wave) instead")
     gcfg.validate()
     if mesh is not None and not _in_mesh and mesh.devices.size > 1:
         from repro.parallel import sharding as sh_mod

@@ -273,6 +273,15 @@ def run_rl(args):
             print(f"epoch {row['epoch']}: {row['iterations']} iters, "
                   f"measured {row['measured_iter_s'] * 1e3:.1f}ms/iter vs "
                   f"predicted {row['predicted_iter_s'] * 1e3:.3f}ms/iter")
+    loads = trainer.publish_moe_load()
+    if loads:
+        from repro.obs import metrics as obs_metrics
+        snap = obs_metrics.snapshot()
+        print("experts held here: " + ", ".join(
+            f"{k}={snap[k]:.0f}" for k in sorted(snap)
+            if k.startswith("moe.assignments.")) +
+            f" assignments; busiest/mean "
+            f"{snap['moe.load_max_over_mean']:.2f}")
     cmp = trainer.engine.compare_with_simulator()
     print(f"measured {cmp['measured_iter_s'] * 1e3:.1f}ms/iter vs "
           f"cost-model {cmp['predicted_iter_s'] * 1e3:.3f}ms/iter "

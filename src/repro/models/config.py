@@ -1,13 +1,20 @@
 """Model configuration covering all assigned architecture families.
 
 One `ModelConfig` dataclass expresses dense GQA transformers, MoE, SSM
-(Mamba), linear attention (RWKV6), hybrid interleaves, encoder-only audio
-backbones, and VLM decoders with a stubbed modality frontend.
+(Mamba), linear attention (RWKV6), gated short convolutions (LFM2),
+hybrid interleaves, encoder-only audio backbones, and VLM decoders with a
+stubbed modality frontend.
 
 The layer stack is described by a repeating *pattern* of `LayerSpec`s: the
 full model is ``pattern`` repeated ``n_layers / len(pattern)`` times. This
 lets us scan over homogeneous super-blocks (jamba's 1:7 attn:mamba period,
-gemma2's local/global alternation) while keeping the HLO small.
+gemma2's local/global alternation) while keeping the HLO small.  A model
+whose layers follow no period (leading dense layers, LFM2's layer types)
+is one pattern of all its layers with one repeat.
+
+An expert layer routes over all ``n_experts`` (the router's width) and
+computes the part of the output that the ``experts_held`` experts from
+``expert_offset`` on give: one chip's share under expert parallelism.
 """
 from __future__ import annotations
 
@@ -23,6 +30,7 @@ class Mixer(str, enum.Enum):
     ATTENTION = "attention"
     MAMBA = "mamba"
     RWKV6 = "rwkv6"
+    SHORTCONV = "shortconv"  # LFM2 gated short convolution
 
 
 class FFN(str, enum.Enum):
@@ -65,11 +73,25 @@ class ModelConfig:
     attn_softcap: Optional[float] = None     # gemma2: 50.0
     final_softcap: Optional[float] = None    # gemma2: 30.0
     causal: bool = True                      # False for encoder-only (hubert)
-    # MoE knobs
+    # MoE knobs: the router's width and experts per token; the experts
+    # held here (0 = all) from ``expert_offset`` on; the expert width
+    # (0 = d_ff)
     n_experts: int = 0
     top_k: int = 0
-    capacity_factor: float = 1.25
+    experts_held: int = 0
+    expert_offset: int = 0
+    expert_d_ff: int = 0
     router_aux_coef: float = 0.01
+    # "softmax" scores, or "sigmoid" (LFM2, DeepSeek-V3); with
+    # ``expert_bias`` a fixed per-expert bias is added to the scores for
+    # the selection only; ``norm_topk`` renormalises the selected scores,
+    # which are then multiplied by ``routed_scale``
+    router_score: str = "softmax"
+    expert_bias: bool = False
+    norm_topk: bool = True
+    routed_scale: float = 1.0
+    # short-conv knobs (LFM2 ``conv_L_cache``)
+    conv_kernel: int = 3
     # Mamba knobs
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
@@ -80,6 +102,8 @@ class ModelConfig:
     frontend: str = "tokens"
     feature_dim: int = 0        # dim of precomputed frame/patch embeddings
     tie_embeddings: bool = False
+    # token embeddings are multiplied by this; None = sqrt(d_model)
+    embed_scale: Optional[float] = None
     norm_eps: float = 1e-5
     # long-context mode: replaces full-attention layers' window (see DESIGN §3)
     long_mode_window: Optional[int] = None
@@ -120,6 +144,29 @@ class ModelConfig:
     @property
     def has_rwkv(self) -> bool:
         return any(s.mixer == Mixer.RWKV6 for s in self.pattern)
+
+    @property
+    def has_shortconv(self) -> bool:
+        return any(s.mixer == Mixer.SHORTCONV for s in self.pattern)
+
+    @property
+    def n_held(self) -> int:
+        """Experts whose weights live here."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def resolved_expert_d_ff(self) -> int:
+        return self.expert_d_ff or self.d_ff
+
+    @property
+    def n_moe_layers(self) -> int:
+        return sum(s.moe for s in self.pattern) * self.n_pattern_repeats
+
+    @property
+    def token_embed_scale(self) -> float:
+        if self.embed_scale is not None:
+            return float(self.embed_scale)
+        return math.sqrt(self.d_model)
 
     @property
     def rwkv_n_heads(self) -> int:
@@ -168,17 +215,21 @@ class ModelConfig:
             elif spec.mixer == Mixer.RWKV6:
                 # r,k,v,g,o projections + decay lora + mixes/bonus/norm
                 per += 5 * d * d + 2 * 64 * d + 9 * d
+            elif spec.mixer == Mixer.SHORTCONV:
+                per += 3 * d * d + self.conv_kernel * d + d * d
             if spec.moe:
                 per += d * self.n_experts  # router
-                per += self.n_experts * self._ffn_params(spec.ffn)
+                per += self.n_experts if self.expert_bias else 0
+                per += self.n_held * self._ffn_params(
+                    spec.ffn, self.resolved_expert_d_ff)
             else:
                 per += self._ffn_params(spec.ffn)
             total += per * self.n_pattern_repeats
         total += d  # final norm
         return total
 
-    def _ffn_params(self, ffn: FFN) -> int:
-        d, f = self.d_model, self.d_ff
+    def _ffn_params(self, ffn: FFN, f: int = 0) -> int:
+        d, f = self.d_model, f or self.d_ff
         if ffn in (FFN.SWIGLU, FFN.GEGLU):
             return 3 * d * f
         if ffn == FFN.RWKV_CHANNEL:
@@ -186,15 +237,17 @@ class ModelConfig:
         return 2 * d * f
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: only top_k experts)."""
+        """Params touched per token (MoE: only the top_k experts, of which
+        on average a held/router-width share live here)."""
         if not self.has_moe:
             return self.param_count()
         total = self.param_count()
+        active = self.top_k * self.n_held / self.n_experts
         for spec in self.pattern:
             if spec.moe:
-                inactive = (self.n_experts - self.top_k) * \
-                    self._ffn_params(spec.ffn)
-                total -= inactive * self.n_pattern_repeats
+                inactive = (self.n_held - active) * self._ffn_params(
+                    spec.ffn, self.resolved_expert_d_ff)
+                total -= int(inactive) * self.n_pattern_repeats
         return total
 
     def validate(self) -> None:
@@ -205,6 +258,9 @@ class ModelConfig:
             assert self.n_heads % self.n_kv_heads == 0
         if self.has_moe:
             assert 0 < self.top_k <= self.n_experts
+            assert 0 <= self.expert_offset
+            assert self.expert_offset + self.n_held <= self.n_experts
+            assert self.router_score in ("softmax", "sigmoid")
         if self.has_rwkv:
             assert self.d_model % self.rwkv_head_dim == 0
         if self.frontend == "features":
@@ -253,6 +309,8 @@ def reduced(cfg: ModelConfig, *, n_layers: int = 2, d_model: int = 256,
         pattern=pattern,
         n_experts=min(cfg.n_experts, n_experts) if cfg.has_moe else 0,
         top_k=min(cfg.top_k, 2) if cfg.has_moe else 0,
+        experts_held=0, expert_offset=0,
+        expert_d_ff=d_model if cfg.expert_d_ff else 0,
         rwkv_head_dim=32,
         feature_dim=(64 if cfg.frontend == "features" else 0),
         long_mode_window=(16 if cfg.long_mode_window is not None else None),

@@ -94,8 +94,8 @@ def init_embeddings(key, cfg: ModelConfig, dtype):
 
 def embed_tokens(params, cfg: ModelConfig, tokens):
     emb = jnp.take(params["tokens"], tokens, axis=0)
-    if cfg.frontend == "tokens":
-        emb = emb * jnp.asarray(math.sqrt(cfg.d_model), emb.dtype)
+    if cfg.frontend == "tokens" and cfg.token_embed_scale != 1.0:
+        emb = emb * jnp.asarray(cfg.token_embed_scale, emb.dtype)
     return emb
 
 

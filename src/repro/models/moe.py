@@ -1,19 +1,26 @@
-"""Mixture-of-Experts FFN — GShard-style grouped one-hot einsum dispatch.
+"""Mixture-of-experts FFN: dropless routing over the router's whole width,
+computing the part of the output that the experts held here give.
 
-Tokens are split into groups of <= `group` tokens; within each group the
-top-k routing builds a [g, E, C] one-hot dispatch tensor via cumulative
-position counting, and dispatch/combine are pure einsums:
+Routing (``route``) scores every token against all ``n_experts`` with a
+softmax or a sigmoid.  With ``expert_bias`` a fixed per-expert bias (a
+buffer: no gradient, no optimizer state) is added to the scores for the
+selection only.  The weights are the selected scores, renormalised
+(``norm_topk``) and multiplied by ``routed_scale``.
 
-    buf  = einsum('zgec,zgd->zecd', dispatch, x)
-    out  = expert_ffn(buf)                      # batched over [Gn, E]
-    y    = einsum('zgec,zecd->zgd', combine, out)
+A layer holds ``experts_held`` experts from ``expert_offset`` on: one
+chip's share under expert parallelism.  An assignment to an expert held
+elsewhere adds nothing here, so the outputs of all the shares sum to the
+whole layer's.  No token is ever dropped:
 
-Einsums partition cleanly under GSPMD (group dim follows the batch
-sharding); scatter/gather-based dispatch triggered involuntary full
-rematerialization + whole-buffer all-reduces (§Perf granite iterations
-1-2, EXPERIMENTS.md).  Tokens beyond per-group capacity are dropped
-(GShard semantics); the router aux loss balances load.  Expert weights
-are FSDP-sharded at rest and gathered to (replicated, TP-on-ffn) at use.
+- training and prefill (``S > 1``) sort the token-to-held-expert
+  assignments by expert and run the held experts as grouped matrix
+  products (``jax.lax.ragged_dot``);
+- decode (``S == 1``) evaluates every held expert on the batch and masks
+  by the gates: the step is bound by reading the held experts' weights,
+  which it reads once either way.
+
+Both paths route with ``route`` and give the same weights.  Each returns
+``load``, the token-to-held-expert assignments per held expert.
 """
 from __future__ import annotations
 
@@ -23,141 +30,125 @@ import jax.numpy as jnp
 from repro.models.config import FFN, ModelConfig
 from repro.models.layers import dense_init
 
-_GROUP = 512
-
 
 def init_moe(key, cfg: ModelConfig, kind: FFN, dtype):
-    d, f, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f = cfg.d_model, cfg.resolved_expert_d_ff
+    E, H = cfg.n_experts, cfg.n_held
     ks = jax.random.split(key, 4)
     if kind in (FFN.SWIGLU, FFN.GEGLU):
         experts = {
-            "w_gate": dense_init(ks[0], (E, d, f), dtype=dtype),
-            "w_up": dense_init(ks[1], (E, d, f), dtype=dtype),
-            "w_down": dense_init(ks[2], (E, f, d), dtype=dtype),
+            "w_gate": dense_init(ks[0], (H, d, f), dtype=dtype),
+            "w_up": dense_init(ks[1], (H, d, f), dtype=dtype),
+            "w_down": dense_init(ks[2], (H, f, d), dtype=dtype),
         }
     else:
         experts = {
-            "w_up": dense_init(ks[0], (E, d, f), dtype=dtype),
-            "w_down": dense_init(ks[1], (E, f, d), dtype=dtype),
+            "w_up": dense_init(ks[0], (H, d, f), dtype=dtype),
+            "w_down": dense_init(ks[1], (H, f, d), dtype=dtype),
         }
-    return {"router": dense_init(ks[3], (d, E), scale=0.02, dtype=dtype),
-            "experts": experts}
+    p = {"router": dense_init(ks[3], (d, E), scale=0.02, dtype=dtype),
+         "experts": experts}
+    if cfg.expert_bias:
+        p["expert_bias"] = jnp.zeros((E,), jnp.float32)
+    return p
 
 
-def _capacity(group_tokens: int, cfg: ModelConfig) -> int:
-    cap = int(group_tokens * cfg.top_k / cfg.n_experts
-              * cfg.capacity_factor)
-    return max(cap, cfg.top_k)
-
-
-def _expert_ffn(experts, cfg: ModelConfig, kind: FFN, h, sfx: str = ""):
-    """h: [Gn, E, C, d] -> [Gn, E, C, d], batched per expert."""
-    from repro.parallel.sharding import hint
-    up = jnp.einsum("zecd,edf->zecf", h, experts["w_up"])
-    up = hint(up, "moe_hidden" + sfx)
-    if kind in (FFN.SWIGLU, FFN.GEGLU):
-        gate = jnp.einsum("zecd,edf->zecf", h, experts["w_gate"])
-        act = jax.nn.silu(gate) if kind == FFN.SWIGLU else \
-            jax.nn.gelu(gate, approximate=True)
-        mid = act * up
-    elif kind == FFN.SQUARED_RELU:
-        mid = jnp.square(jax.nn.relu(up))
-    else:
-        mid = jax.nn.gelu(up, approximate=True)
-    mid = hint(mid, "moe_hidden" + sfx)
-    return jnp.einsum("zecf,efd->zecd", mid, experts["w_down"])
-
-
-def _dense_decode_moe(params, cfg: ModelConfig, kind: FFN, x):
-    """Single-token decode: evaluate ALL experts densely and mask by the
-    top-k gates.  At S=1 the step is weight-read bound (every expert's
-    weights stream from HBM regardless), so dense evaluation beats
-    dispatch machinery — the grouped one-hot path is training-optimal
-    but inflates decode (§Perf mixtral notes)."""
-    B, S, d = x.shape
+def route(params, cfg: ModelConfig, x2):
+    """x2: [T, d] -> (weights [T, K] float32, experts [T, K] over the
+    router's width, aux loss)."""
     E, K = cfg.n_experts, cfg.top_k
-    xt = x.reshape(B, d)
-    logits = (xt @ params["router"]).astype(jnp.float32)
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(gates, K)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    w_mask = jnp.zeros((B, E), jnp.float32)
-    for k in range(K):
-        w_mask = w_mask + top_w[:, k, None] * jax.nn.one_hot(
-            top_e[:, k], E, dtype=jnp.float32)
+    with jax.named_scope("moe.route"):
+        logits = (x2 @ params["router"]).astype(jnp.float32)
+        scores = (jax.nn.sigmoid(logits) if cfg.router_score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        select = scores
+        if cfg.expert_bias:
+            select = scores + jax.lax.stop_gradient(
+                params["expert_bias"].astype(jnp.float32))
+        _, top_e = jax.lax.top_k(select, K)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+        if cfg.norm_topk:
+            top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-6)
+        top_w = top_w * cfg.routed_scale
+        aux = jnp.zeros((), jnp.float32)
+        if cfg.router_aux_coef:
+            # Switch-style load balancing over the router's width
+            me = scores.mean(axis=0)
+            ce = jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32).mean(0)
+            aux = cfg.router_aux_coef * E * jnp.sum(me * ce)
+    return top_w, top_e, aux
 
-    up = jnp.einsum("bd,edf->bef", xt, params["experts"]["w_up"])
-    if kind in (FFN.SWIGLU, FFN.GEGLU):
-        gate = jnp.einsum("bd,edf->bef", xt, params["experts"]["w_gate"])
-        act = jax.nn.silu(gate) if kind == FFN.SWIGLU else \
-            jax.nn.gelu(gate, approximate=True)
-        mid = act * up
-    elif kind == FFN.SQUARED_RELU:
-        mid = jnp.square(jax.nn.relu(up))
-    else:
-        mid = jax.nn.gelu(up, approximate=True)
-    out = jnp.einsum("bef,efd->bed", mid, params["experts"]["w_down"])
-    y = jnp.einsum("bed,be->bd", out, w_mask.astype(out.dtype))
-    aux = jnp.zeros((), jnp.float32)
-    return y.reshape(B, S, d).astype(x.dtype), aux
+
+def _held(cfg: ModelConfig, top_e):
+    """(local expert ids [T, K], held mask [T, K])."""
+    local = top_e - cfg.expert_offset
+    return local, (local >= 0) & (local < cfg.n_held)
+
+
+def _activate(kind: FFN, gate, up):
+    if kind == FFN.SWIGLU:
+        return jax.nn.silu(gate) * up
+    if kind == FFN.GEGLU:
+        return jax.nn.gelu(gate, approximate=True) * up
+    if kind == FFN.SQUARED_RELU:
+        return jnp.square(jax.nn.relu(up))
+    return jax.nn.gelu(up, approximate=True)
+
+
+def _grouped_experts(experts, cfg: ModelConfig, kind: FFN, x2, top_w,
+                     top_e):
+    """Dropless grouped evaluation: x2 [T, d] -> ([T, d], load [H])."""
+    T, d = x2.shape
+    K, H = cfg.top_k, cfg.n_held
+    local, held = _held(cfg, top_e)
+    flat = jnp.where(held, local, H).reshape(-1)          # [T*K]
+    # held assignments first, by expert; at most min(K, H) per token
+    order = jnp.argsort(flat, stable=True)[:T * min(K, H)]
+    load = jnp.bincount(flat, length=H + 1)[:H].astype(jnp.int32)
+    tok = order // K
+    # rows past the held assignments belong to no group, and ragged_dot
+    # leaves its output there undefined (uninitialised on the TPU): each
+    # product's input and output are zeroed there, which also zeroes
+    # whatever the backward pass computes for those rows
+    live = (jnp.arange(order.shape[0]) < load.sum())[:, None]
+
+    def rdot(lhs, w):
+        return jnp.where(live, jax.lax.ragged_dot(lhs, w, load), 0)
+
+    xs = jnp.where(live, jnp.take(x2, tok, axis=0), 0)
+    gate = rdot(xs, experts["w_gate"]) if "w_gate" in experts else None
+    up = rdot(xs, experts["w_up"])
+    out = rdot(_activate(kind, gate, up), experts["w_down"])
+    w = jnp.where(held, top_w, 0.0).reshape(-1)[order]
+    y = jnp.zeros((T, d), jnp.float32).at[tok].add(
+        out.astype(jnp.float32) * w[:, None])
+    return y.astype(x2.dtype), load
+
+
+def _dense_experts(experts, cfg: ModelConfig, kind: FFN, x2, top_w, top_e):
+    """Every held expert on every row, masked by the gates: x2 [T, d] ->
+    ([T, d], load [H])."""
+    H = cfg.n_held
+    local, held = _held(cfg, top_e)
+    hot = jax.nn.one_hot(jnp.where(held, local, H), H,
+                         dtype=jnp.float32)                # [T, K, H]
+    w_mask = jnp.einsum("tk,tkh->th", top_w, hot)
+    load = hot.sum(axis=(0, 1)).astype(jnp.int32)
+    up = jnp.einsum("td,hdf->thf", x2, experts["w_up"])
+    gate = (jnp.einsum("td,hdf->thf", x2, experts["w_gate"])
+            if "w_gate" in experts else None)
+    out = jnp.einsum("thf,hfd->thd", _activate(kind, gate, up),
+                     experts["w_down"])
+    y = jnp.einsum("thd,th->td", out, w_mask.astype(out.dtype))
+    return y.astype(x2.dtype), load
 
 
 def apply_moe(params, cfg: ModelConfig, kind: FFN, x):
-    """x: [B, S, d] -> (y, aux_loss)."""
-    from repro.parallel.sharding import hint
+    """x: [B, S, d] -> (y, aux_loss, load [n_held])."""
     B, S, d = x.shape
-    E, K = cfg.n_experts, cfg.top_k
-    if S == 1:
-        return _dense_decode_moe(params, cfg, kind, x)
-    # group within a sequence; at decode (S == 1) group across batch
-    # slices instead — per-token groups would inflate capacity slots to
-    # E*top_k per token (16x tokens for mixtral) — while keeping >= 16
-    # groups so the group dim still shards over the data axis
-    g = min(_GROUP, S) if S > 1 else max(min(B // 16, _GROUP), 1)
-    assert (B * S) % g == 0
-    Gn = B * S // g
-    C = _capacity(g, cfg)
-    xg = x.reshape(Gn, g, d)
-
-    logits = (xg @ params["router"]).astype(jnp.float32)    # [Gn, g, E]
-    gates = jax.nn.softmax(logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(gates, K)                  # [Gn, g, K]
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-
-    # --- position-in-expert via cumulative counting over the K slots ---
-    dispatch = jnp.zeros((Gn, g, E, C), x.dtype)
-    combine = jnp.zeros((Gn, g, E, C), jnp.float32)
-    counts = jnp.zeros((Gn, E), jnp.float32)
-    for k in range(K):
-        oh = jax.nn.one_hot(top_e[..., k], E, dtype=jnp.float32)  # [Gn,g,E]
-        pos = jnp.cumsum(oh, axis=1) - oh + counts[:, None, :]
-        within = (pos < C).astype(jnp.float32) * oh
-        pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C,
-                                dtype=jnp.float32)          # [Gn,g,E,C]
-        sel = within[..., None] * pos_oh
-        dispatch = dispatch + sel.astype(x.dtype)
-        combine = combine + top_w[..., k, None, None] * sel
-        counts = counts + oh.sum(axis=1)
-
-    # expert-parallel when E can cover the model axis; TP-on-ffn otherwise
-    ep = E >= 16
-    sfx = "_ep" if ep else ""
-    experts = {
-        k2: hint(w, ("moe_w_out" if k2 in ("w_down", "w_value")
-                     else "moe_w_in") + sfx)
-        for k2, w in params["experts"].items()}
-    if ep:
-        dispatch = hint(dispatch, "moe_onehot_ep")
-        combine = hint(combine, "moe_onehot_ep")
-
-    buf = jnp.einsum("zgec,zgd->zecd", dispatch, xg)
-    buf = hint(buf, "moe_buffer" + sfx)
-    out = _expert_ffn(experts, cfg, kind, buf, sfx)
-    y = jnp.einsum("zgec,zecd->zgd", combine.astype(x.dtype), out)
-
-    # --- aux load-balancing loss (Switch-style) ---
-    me = gates.reshape(-1, E).mean(axis=0)
-    ce = jax.nn.one_hot(top_e[..., 0].reshape(-1), E,
-                        dtype=jnp.float32).mean(axis=0)
-    aux = cfg.router_aux_coef * E * jnp.sum(me * ce)
-    return y.reshape(B, S, d), aux
+    x2 = x.reshape(B * S, d)
+    top_w, top_e, aux = route(params, cfg, x2)
+    experts_fn = _dense_experts if S == 1 else _grouped_experts
+    with jax.named_scope("moe.experts"):
+        y, load = experts_fn(params["experts"], cfg, kind, x2, top_w, top_e)
+    return y.reshape(B, S, d), aux, load

@@ -18,6 +18,7 @@ from repro.models import cache as cache_mod
 from repro.models import mamba as mamba_mod
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
+from repro.models import shortconv as shortconv_mod
 from repro.models.config import FFN, Mixer, ModelConfig
 from repro.models.layers import (apply_ffn, embed_features, embed_tokens,
                                  init_embeddings, init_ffn, init_rmsnorm,
@@ -45,6 +46,8 @@ def _init_layer(key, cfg: ModelConfig, spec) -> Dict[str, Any]:
         p["mamba"] = mamba_mod.init_mamba(ks[0], cfg, dt)
     elif spec.mixer == Mixer.RWKV6:
         p["rwkv"] = rwkv_mod.init_rwkv(ks[0], cfg, dt)
+    elif spec.mixer == Mixer.SHORTCONV:
+        p["shortconv"] = shortconv_mod.init_shortconv(ks[0], cfg, dt)
     if spec.moe:
         p["moe"] = moe_mod.init_moe(ks[1], cfg, spec.ffn, dt)
     else:
@@ -99,11 +102,18 @@ def embed_inputs(params, cfg: ModelConfig, inputs: Dict[str, Any]):
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+def _stack_loads(loads):
+    """Per-layer loads scanned over the repeats ([R, n_held] each) ->
+    [n_moe_layers, n_held] in layer order."""
+    return jnp.stack(loads, axis=1).reshape(-1, loads[0].shape[-1])
+
+
 def _layer_forward(p, cfg: ModelConfig, spec, x, positions, *, long_mode,
                    want_cache, max_cache_len):
-    """Returns (x, aux, cache_entry_or_None)."""
+    """Returns (x, aux, load, cache_entry_or_None); ``load`` is the
+    expert layer's assignments per held expert (None for a dense FFN)."""
     aux = jnp.zeros((), jnp.float32)
-    entry = None
+    load = entry = None
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
     if spec.mixer == Mixer.ATTENTION:
         window = cache_mod.effective_window(cfg, spec, long_mode)
@@ -135,6 +145,12 @@ def _layer_forward(p, cfg: ModelConfig, spec, x, positions, *, long_mode,
             entry = st
         else:
             y = rwkv_mod.apply_rwkv(p["rwkv"], cfg, h)
+    elif spec.mixer == Mixer.SHORTCONV:
+        if want_cache:
+            y, entry = shortconv_mod.apply_shortconv(
+                p["shortconv"], cfg, h, return_state=True)
+        else:
+            y = shortconv_mod.apply_shortconv(p["shortconv"], cfg, h)
     else:
         raise ValueError(spec.mixer)
     # name the mixer output so the remat policy can save it: recomputing
@@ -145,7 +161,7 @@ def _layer_forward(p, cfg: ModelConfig, spec, x, positions, *, long_mode,
 
     h = rmsnorm(p["norm2"], x, cfg.norm_eps)
     if spec.moe:
-        y, a = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
+        y, a, load = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
         aux = aux + a
     else:
         shifted = token_shift(h) if spec.ffn == FFN.RWKV_CHANNEL else None
@@ -154,7 +170,7 @@ def _layer_forward(p, cfg: ModelConfig, spec, x, positions, *, long_mode,
             entry = dict(entry or {})
             entry["cm_shift"] = h[:, -1, :]
     x = hint(x + y, "residual")
-    return x, aux, entry
+    return x, aux, load, entry
 
 
 def forward(params, cfg: ModelConfig, inputs: Dict[str, Any], *,
@@ -162,7 +178,9 @@ def forward(params, cfg: ModelConfig, inputs: Dict[str, Any], *,
             max_cache_len: Optional[int] = None, remat: bool = True,
             remat_policy: str = "save_mixer"):
     """Full-sequence forward. Returns dict with logits [B,S,V], aux_loss,
-    and (optionally) a decode cache primed with the sequence."""
+    ``moe_load`` (MoE models: [n_moe_layers, n_held] token-to-held-expert
+    assignments) and (optionally) a decode cache primed with the
+    sequence."""
     x = embed_inputs(params, cfg, inputs)
     B, S, _ = x.shape
     positions = jnp.arange(S)
@@ -171,16 +189,18 @@ def forward(params, cfg: ModelConfig, inputs: Dict[str, Any], *,
 
     def superblock(x, block_params):
         aux = jnp.zeros((), jnp.float32)
-        entries = {}
+        entries, loads = {}, []
         for j, spec in enumerate(cfg.pattern):
-            x, a, entry = _layer_forward(
+            x, a, load, entry = _layer_forward(
                 block_params[f"layer{j}"], cfg, spec, x, positions,
                 long_mode=long_mode, want_cache=return_cache,
                 max_cache_len=max_cache_len)
             aux = aux + a
+            if load is not None:
+                loads.append(load)
             if entry is not None:
                 entries[f"layer{j}"] = entry
-        return x, (aux, entries)
+        return x, (aux, loads, entries)
 
     if remat and remat_policy == "save_mixer":
         policy = jax.checkpoint_policies.save_only_these_names("mixer_out")
@@ -194,10 +214,13 @@ def forward(params, cfg: ModelConfig, inputs: Dict[str, Any], *,
         x, out = block_fn(x, bp)
         return x, out
 
-    x, (auxes, entries) = jax.lax.scan(scan_body, x, params["blocks"])
+    x, (auxes, loads, entries) = jax.lax.scan(scan_body, x,
+                                              params["blocks"])
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = hint(unembed(params["embed"], cfg, x), "logits")
     out = {"logits": logits, "aux_loss": jnp.sum(auxes), "hidden": x}
+    if loads:
+        out["moe_load"] = _stack_loads(loads)
     if return_cache:
         cache = {"blocks": entries, "pos": jnp.asarray(S, jnp.int32)}
         out["cache"] = cache
@@ -259,7 +282,7 @@ def _layer_prefill_chunk(p, cfg: ModelConfig, spec, x, cache_entry, pos,
 
     h = rmsnorm(p["norm2"], x, cfg.norm_eps)
     if spec.moe:
-        y, _ = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
+        y, _, _ = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
     else:
         if spec.ffn == FFN.RWKV_CHANNEL:
             shifted = jnp.concatenate(
@@ -358,7 +381,7 @@ def _layer_verify_chunk(p, cfg: ModelConfig, spec, x, cache_entry, pos, *,
 
     h = rmsnorm(p["norm2"], x, cfg.norm_eps)
     if spec.moe:
-        y, _ = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
+        y, _, _ = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
     else:
         y = apply_ffn(p["ffn"], cfg, spec.ffn, h)
     return x + y, {"k": k, "v": v}
@@ -419,7 +442,8 @@ def _split_entry(spec, entry):
 
 def _layer_decode(p, cfg: ModelConfig, spec, x, cache_entry, pos, layer, *,
                   long_mode):
-    """x: [B,1,d]. Returns (x, new_cache_entry).
+    """x: [B,1,d]. Returns (x, new_cache_entry, load): ``load`` as in
+    ``_layer_forward``.
 
     `cache_entry` holds this layer's recurrent leaves as per-repeat
     slices and, for attention, the k/v stacks of every repeat
@@ -460,11 +484,17 @@ def _layer_decode(p, cfg: ModelConfig, spec, x, cache_entry, pos, layer, *,
         y, st_new = rwkv_mod.apply_rwkv(p["rwkv"], cfg, h, state=st,
                                         return_state=True)
         new_entry.update(st_new)
+    elif spec.mixer == Mixer.SHORTCONV:
+        y, st_new = shortconv_mod.apply_shortconv(
+            p["shortconv"], cfg, h, state={"conv": cache_entry["conv"]},
+            return_state=True)
+        new_entry.update(st_new)
     x = x + y
 
+    load = None
     h = rmsnorm(p["norm2"], x, cfg.norm_eps)
     if spec.moe:
-        y, _ = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
+        y, _, load = moe_mod.apply_moe(p["moe"], cfg, spec.ffn, h)
     else:
         if spec.ffn == FFN.RWKV_CHANNEL:
             shifted = cache_entry["cm_shift"][:, None, :].astype(h.dtype)
@@ -472,12 +502,13 @@ def _layer_decode(p, cfg: ModelConfig, spec, x, cache_entry, pos, layer, *,
             new_entry["cm_shift"] = h[:, -1, :]
         else:
             y = apply_ffn(p["ffn"], cfg, spec.ffn, h)
-    return x + y, new_entry
+    return x + y, new_entry, load
 
 
 def decode_step(params, cfg: ModelConfig, tokens, cache, *,
-                long_mode: bool = False):
-    """tokens: [B, 1] int32. Returns (logits [B, V], new_cache).
+                long_mode: bool = False, with_load: bool = False):
+    """tokens: [B, 1] int32. Returns (logits [B, V], new_cache), and with
+    ``with_load`` the step's ``moe_load`` as ``forward`` gives it.
 
     ``cache["pos"]`` is a scalar (every row at the same position) or a
     [B] vector of per-row positions — the natively batched fast path the
@@ -499,22 +530,26 @@ def decode_step(params, cfg: ModelConfig, tokens, cache, *,
     def scan_body(carry, inp):
         x, kv = carry
         bp, state, r = inp
-        kv, new_state = dict(kv), {}
+        kv, new_state, loads = dict(kv), {}, []
         for name, spec in zip(names, cfg.pattern):
-            x, ne = _layer_decode(bp[name], cfg, spec, x,
-                                  {**kv[name], **state[name]}, pos, r,
-                                  long_mode=long_mode)
+            x, ne, load = _layer_decode(bp[name], cfg, spec, x,
+                                        {**kv[name], **state[name]}, pos, r,
+                                        long_mode=long_mode)
             kv[name], new_state[name] = _split_entry(spec, ne)
-        return (x, kv), new_state
+            if load is not None:
+                loads.append(load)
+        return (x, kv), (new_state, loads)
 
     kv, state = {}, {}
     for name, spec in zip(names, cfg.pattern):
         kv[name], state[name] = _split_entry(spec, cache["blocks"][name])
-    (x, kv), state = jax.lax.scan(
+    (x, kv), (state, loads) = jax.lax.scan(
         scan_body, (x, kv),
         (params["blocks"], state, jnp.arange(cfg.n_pattern_repeats)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["embed"], cfg, x)[:, 0]
     new_blocks = {name: {**kv[name], **state[name]} for name in kv}
     new_cache = {"blocks": new_blocks, "pos": pos + 1}
+    if with_load:
+        return logits, new_cache, _stack_loads(loads)
     return logits, new_cache

@@ -1,6 +1,10 @@
 """Adam/AdamW + gradient clipping + LR schedules, from scratch (no optax
 in this environment).  Mixed-precision aware: moments can be stored in a
 reduced dtype for memory-constrained configs (see DESIGN.md jamba notes).
+
+Leaves under a ``FROZEN`` key are fixed buffers: ``partition`` splits
+them off, they get no gradient and no optimizer state, and
+``adam_update`` returns them as it got them.
 """
 from __future__ import annotations
 
@@ -25,7 +29,42 @@ class AdamConfig:
     min_lr_frac: float = 0.1
 
 
+# an expert layer's selection bias (models/moe.py) is a buffer
+FROZEN = ("expert_bias",)
+
+
+def partition(tree):
+    """(trainable, frozen): ``tree`` split at ``FROZEN`` dict keys, both
+    in its nesting (empty where there is nothing frozen)."""
+    if isinstance(tree, dict):
+        train, frozen = {}, {}
+        for k, v in tree.items():
+            if k in FROZEN:
+                frozen[k] = v
+            else:
+                train[k], frozen[k] = partition(v)
+        return train, frozen
+    if isinstance(tree, (tuple, list)):
+        parts = [partition(v) for v in tree]
+        return (type(tree)(p[0] for p in parts),
+                type(tree)(p[1] for p in parts))
+    return tree, {}
+
+
+def join(train, frozen):
+    """The inverse of ``partition``."""
+    if isinstance(train, dict):
+        out = dict(train)
+        for k, v in frozen.items():
+            out[k] = join(train[k], v) if k in train else v
+        return out
+    if isinstance(train, (tuple, list)):
+        return type(train)(join(t, f) for t, f in zip(train, frozen))
+    return train
+
+
 def init_adam_state(params, cfg: AdamConfig):
+    params = partition(params)[0]
     mdt = jnp.dtype(cfg.moment_dtype)
     zeros = lambda p: jnp.zeros_like(p, dtype=mdt)
     return {
@@ -61,7 +100,10 @@ def clip_by_global_norm(grads, max_norm: float):
 
 
 def adam_update(params, grads, state, cfg: AdamConfig):
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics).  ``grads`` covers the
+    trainable leaves (or all, frozen ones then ignored)."""
+    params, frozen = partition(params)
+    grads = partition(grads)[0]
     step = state["step"] + 1
     if cfg.grad_clip_norm is not None:
         grads, gnorm = clip_by_global_norm(grads, cfg.grad_clip_norm)
@@ -94,4 +136,4 @@ def adam_update(params, grads, state, cfg: AdamConfig):
     unf = lambda i: jax.tree_util.tree_unflatten(
         treedef, [o[i] for o in outs])
     new_state = {"step": step, "m": unf(1), "v": unf(2)}
-    return unf(0), new_state, {"lr": lr, "grad_norm": gnorm}
+    return join(unf(0), frozen), new_state, {"lr": lr, "grad_norm": gnorm}

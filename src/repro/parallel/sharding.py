@@ -3,7 +3,7 @@
 Two pieces:
 
 1. ``hint(x, name)`` — models call this on named intermediate activations
-   (residual stream, mamba inner, moe buffer, logits...).  Outside
+   (residual stream, mamba inner, logits...).  Outside
    ``use_hints`` it is the identity, so all models run unchanged on a
    single CPU device.  Inside ``use_hints(rules)`` each named activation
    gets a ``with_sharding_constraint`` — this is where the distribution
@@ -97,25 +97,6 @@ def default_activation_rules(*, data_axes=("data",), model_axis="model",
         "mamba_inner": P(da, None, m),
         # [B, S, H, hd, hd']-ish rwkv head state dims: heads over model
         "rwkv_heads": P(da, None, m, None),
-        # [Gn, E, C, d] moe dispatch buffer — groups over data; experts
-        # over model in EP mode
-        "moe_buffer": P(da, None, None, None),
-        "moe_buffer_ep": P(da, m, None, None),
-        # expert weights at USE time (storage stays FSDP-sharded):
-        #  - EP mode (E >= model axis): experts over model, ffn unsharded —
-        #    the combine einsum contracts sharded-E => the TP all-reduce
-        #    carries token-sized tensors, not the capacity buffer
-        #  - TP mode (small E): ffn dim over model
-        "moe_w_in_ep": P(m, None, None),   # [E, d, f]
-        "moe_w_out_ep": P(m, None, None),  # [E, f, d]
-        "moe_w_in": P(None, None, m),
-        "moe_w_out": P(None, m, None),
-        # [Gn, g, E, C] dispatch/combine one-hots in EP mode
-        "moe_onehot_ep": P(da, None, m, None),
-        # [Gn, E, C, f] expert hidden activations: 2D-sharded (GSPMD left
-        # to itself gathers the group dim to apply the ffn sharding)
-        "moe_hidden": P(da, None, None, m),
-        "moe_hidden_ep": P(da, m, None, None),
         # [B, S, V]
         "logits": P(da, None, m),
         # decode: [B, 1, d]

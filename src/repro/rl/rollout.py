@@ -41,7 +41,9 @@ def _sample(rng, logits, cfg: SamplerConfig):
 def generate(params, cfg: ModelConfig, prompts, rng,
              sampler: SamplerConfig) -> Dict[str, jnp.ndarray]:
     """prompts: [B, P] int32.  Returns dict with
-    sequences [B, P+N], gen_tokens [B, N], logprobs [B, N], mask [B, N]."""
+    sequences [B, P+N], gen_tokens [B, N], logprobs [B, N], mask [B, N],
+    and for an MoE model ``moe_load``: the prefill's and every decode
+    step's token-to-held-expert assignments ([n_moe_layers, n_held])."""
     B, P = prompts.shape
     N = sampler.max_new_tokens
     out = T.forward(params, cfg, {"tokens": prompts}, return_cache=True,
@@ -52,24 +54,32 @@ def generate(params, cfg: ModelConfig, prompts, rng,
     rngs = jax.random.split(rng, N)
     tok0, lp0 = _sample(rngs[0], logits0, sampler)
 
+    moe = cfg.has_moe
+
     def step(carry, rng_t):
-        cache, tok, alive = carry
-        logits, cache = T.decode_step(params, cfg, tok[:, None], cache)
+        cache, tok, alive, *load = carry
+        logits, cache, *step_load = T.decode_step(
+            params, cfg, tok[:, None], cache, with_load=moe)
         nxt, lp = _sample(rng_t, logits, sampler)
         alive_next = sampling.next_alive(alive, tok, sampler.eos_token)
-        return (cache, nxt, alive_next), (nxt, lp, alive_next)
+        load = [load[0] + step_load[0]] if moe else []
+        return (cache, nxt, alive_next, *load), (nxt, lp, alive_next)
 
     # a prompt that already ends with EOS starts dead: its first sampled
     # token is recorded but invalid (shared edge semantics with genserve)
     alive0 = sampling.initial_alive(prompts, sampler.eos_token)
-    (_, _, _), (toks, lps, alives) = jax.lax.scan(
-        step, (cache, tok0, alive0), rngs[1:])
+    load0 = [out["moe_load"]] if moe else []
+    (_, _, _, *load), (toks, lps, alives) = jax.lax.scan(
+        step, (cache, tok0, alive0, *load0), rngs[1:])
     gen = jnp.concatenate([tok0[:, None], toks.T], axis=1)       # [B, N]
     logprobs = jnp.concatenate([lp0[:, None], lps.T], axis=1)
     mask = jnp.concatenate([alive0[:, None], alives.T], axis=1)
     sequences = jnp.concatenate([prompts, gen], axis=1)
-    return {"sequences": sequences, "gen_tokens": gen,
-            "logprobs": logprobs, "mask": mask.astype(jnp.float32)}
+    ro = {"sequences": sequences, "gen_tokens": gen,
+          "logprobs": logprobs, "mask": mask.astype(jnp.float32)}
+    if moe:
+        ro["moe_load"] = load[0]
+    return ro
 
 
 def sequence_logprobs(params, cfg: ModelConfig, sequences, gen_start: int):

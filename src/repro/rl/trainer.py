@@ -16,7 +16,6 @@ public API and metrics dict are unchanged from the pre-engine version.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -86,10 +85,11 @@ def make_actor_step(cfg: ModelConfig, rl: RLConfig) -> Callable:
     """The actor update, unjitted: ``(params, opt_state, batch,
     gen_start) -> (params, opt_state, metrics)``, one clipped-surrogate
     step on a rollout batch (``sequences``, ``logp_old``, ``advantages``,
-    ``mask``) followed by Adam."""
-    def actor_loss(params, batch, gen_start):
+    ``mask``) followed by Adam.  For an MoE model the metrics hold the
+    forward's ``moe_load``."""
+    def actor_loss(train, frozen, batch, gen_start):
         lp_new, out = rollout.sequence_logprobs(
-            params, cfg, batch["sequences"], gen_start)
+            adam.join(train, frozen), cfg, batch["sequences"], gen_start)
         pl = losses.ppo_policy_loss(
             lp_new, batch["logp_old"], batch["advantages"],
             batch["mask"], clip_eps=rl.clip_eps)
@@ -98,11 +98,15 @@ def make_actor_step(cfg: ModelConfig, rl: RLConfig) -> Callable:
             logits = out["logits"][:, gen_start - 1:-1]
             loss = loss - rl.entropy_coef * losses.entropy_bonus(
                 logits, batch["mask"])
+        if "moe_load" in out:
+            pl = {**pl, "moe_load": out["moe_load"]}
         return loss, pl
 
     def actor_step(params, opt_state, batch, gen_start):
+        # frozen buffers (adam.FROZEN) are not differentiated
+        train, frozen = adam.partition(params)
         (loss, pl), grads = jax.value_and_grad(
-            actor_loss, has_aux=True)(params, batch, gen_start)
+            actor_loss, has_aux=True)(train, frozen, batch, gen_start)
         new_params, new_opt, om = adam.adam_update(
             params, grads, opt_state, adam.AdamConfig(lr=rl.lr))
         return new_params, new_opt, {**pl, "loss": loss, **om}
@@ -135,6 +139,8 @@ class RLTrainer:
             temperature=rl_cfg.temperature, eos_token=EOS,
             greedy=rl_cfg.greedy)
         self._shard_cache: Dict[Tuple, Callable] = {}
+        # device-side expert-load tallies by phase (count_moe_load)
+        self._moe_load: Dict[str, jnp.ndarray] = {}
         self._jit()
 
         # plan-driven engine: the plan decides task colocation/concurrency
@@ -208,9 +214,14 @@ class RLTrainer:
     def _jit(self):
         cfg, rl = self.cfg, self.rl
 
-        self._generate_raw = functools.partial(
-            rollout.generate, cfg=cfg, sampler=self.sampler)
-        self._generate = jax.jit(self._generate_raw, static_argnames=())
+        sampler = self.sampler
+
+        # a named function, so that the program is ``jit_generate`` in a
+        # profiler trace (a jitted ``functools.partial`` has no name)
+        def generate(params, prompts, rng):
+            return rollout.generate(params, cfg, prompts, rng, sampler)
+        self._generate_raw = generate
+        self._generate = jax.jit(generate)
 
         def ref_logp(params, sequences, gen_start):
             lp, _ = rollout.sequence_logprobs(params, cfg, sequences,
@@ -260,6 +271,7 @@ class RLTrainer:
     # placements keep the original jitted paths untouched.
 
     def _opt_shardings(self, placement, pshard):
+        pshard = adam.partition(pshard)[0]
         return {"step": placement.replicated_sharding(),
                 "m": pshard, "v": pshard}
 
@@ -411,6 +423,30 @@ class RLTrainer:
         if bb.get("bundle") is not None and \
                 any(t.kind == TaskKind.TRAIN for t in stage_tasks):
             ensure_train_batch(self, bb)
+
+    def count_moe_load(self, phase: str, load) -> None:
+        """Add a program's token-to-held-expert assignments
+        ([n_moe_layers, n_held]) to the tally of ``phase`` ("gen" or
+        "train") on the device: nothing is read here."""
+        prev = self._moe_load.get(phase)
+        self._moe_load[phase] = load if prev is None else prev + load
+
+    def publish_moe_load(self) -> Dict[str, np.ndarray]:
+        """Read the expert-load tallies once, add them to the metrics
+        registry (counters ``moe.assignments.<phase>``, gauge
+        ``moe.load_max_over_mean``: the busiest held expert of any layer
+        against the mean) and start new tallies.  Returns the loads."""
+        from repro.obs import metrics as obs_metrics
+        loads = {k: np.asarray(v) for k, v in self._moe_load.items()}
+        self._moe_load = {}
+        for phase, load in loads.items():
+            obs_metrics.counter(f"moe.assignments.{phase}").inc(
+                int(load.sum()))
+        if loads:
+            total = sum(loads.values())
+            obs_metrics.gauge("moe.load_max_over_mean").set(
+                float(total.max()) / max(float(total.mean()), 1e-9))
+        return loads
 
     def fill_metrics(self) -> Dict[str, float]:
         return {"reward_mean": 0.0, "kl": 0.0, "gen_len": 0.0,

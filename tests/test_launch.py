@@ -64,15 +64,17 @@ def test_skip_rules():
         ("rwkv6-3b", "long_500k"): False,
         ("jamba-1.5-large-398b", "long_500k"): False,
         ("phi3-medium-14b", "train_4k"): False,
+        ("lfm2-8b-a1b", "long_500k"): True,     # full attention layers
+        ("lfm2-8b-a1b", "train_4k"): False,
     }
     for (arch, shape), should_skip in rules.items():
         reason = skip_reason(archs.get(arch), SHAPES[shape])
         assert (reason is not None) == should_skip, \
             f"{arch}/{shape}: {reason}"
-    # total runnable pairs: 33 of 40
+    # total runnable pairs: 36 of 44
     runnable = sum(1 for a in archs.ARCHS for s in SHAPES
                    if skip_reason(archs.get(a), SHAPES[s]) is None)
-    assert runnable == 33
+    assert runnable == 36
 
 
 def test_input_specs_match_real_batches():

@@ -72,22 +72,22 @@ def test_softcap_bounds():
                                np.asarray(x))
 
 
-def _moe_cfg(cf=1.25):
+def _moe_cfg():
     return ModelConfig(name="m", n_layers=1, d_model=32, n_heads=2,
                        n_kv_heads=2, d_ff=64, vocab_size=64,
                        pattern=(LayerSpec(Mixer.ATTENTION, FFN.SWIGLU,
                                           moe=True),),
-                       n_experts=4, top_k=2, capacity_factor=cf,
-                       dtype="float32")
+                       n_experts=4, top_k=2, dtype="float32")
 
 
 def test_moe_matches_dense_expert_sum_at_high_capacity():
-    """With capacity high enough for zero drops, the sort/scatter dispatch
-    must equal the dense (all-experts) weighted computation."""
-    cfg = _moe_cfg(cf=8.0)
+    """The grouped (sorted, ragged) evaluation must equal the dense
+    (all-experts) weighted computation: no capacity, nothing dropped."""
+    cfg = _moe_cfg()
     p = moe.init_moe(KEY, cfg, FFN.SWIGLU, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 16, cfg.d_model))
-    y, aux = moe.apply_moe(p, cfg, FFN.SWIGLU, x)
+    y, aux, load = moe.apply_moe(p, cfg, FFN.SWIGLU, x)
+    assert int(load.sum()) == 2 * 16 * cfg.top_k
     # dense reference
     T = 2 * 16
     xt = x.reshape(T, cfg.d_model)
@@ -110,11 +110,21 @@ def test_moe_matches_dense_expert_sum_at_high_capacity():
 
 
 def test_moe_capacity_drops_bounded():
-    cfg = _moe_cfg(cf=0.5)  # forced drops
+    """Dropless under the most skewed routing: a router that sends every
+    token to experts 0 and 1 loads them with all 32 tokens (GShard at
+    capacity factor 1.25 would keep 20 each), and every token still gets
+    both experts' outputs, as the dense evaluation gives them."""
+    cfg = _moe_cfg()
     p = moe.init_moe(KEY, cfg, FFN.SWIGLU, jnp.float32)
-    x = jax.random.normal(KEY, (2, 16, cfg.d_model))
-    y, _ = moe.apply_moe(p, cfg, FFN.SWIGLU, x)
-    assert bool(jnp.isfinite(y).all())
+    x = jnp.abs(jax.random.normal(KEY, (2, 16, cfg.d_model)))
+    p["router"] = jnp.zeros_like(p["router"]).at[:, :2].set(1.0)
+    y, _, load = moe.apply_moe(p, cfg, FFN.SWIGLU, x)
+    np.testing.assert_array_equal(np.asarray(load), [32, 32, 0, 0])
+    y_dense, _, _ = moe.apply_moe(p, cfg, FFN.SWIGLU,
+                                  x.reshape(32, 1, cfg.d_model))
+    np.testing.assert_allclose(np.asarray(y.reshape(32, -1)),
+                               np.asarray(y_dense.reshape(32, -1)),
+                               rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("chunks", [(8, 64), (16, 32)])
@@ -385,17 +395,16 @@ def test_effective_window_long_mode():
 
 
 def test_moe_dense_decode_matches_dispatch():
-    """The S=1 dense-decode path must agree with the grouped dispatch
-    (high capacity => no drops)."""
+    """The S=1 dense-decode path must agree with the grouped dispatch."""
     import jax
     import jax.numpy as jnp
-    cfg = _moe_cfg(cf=8.0)
+    cfg = _moe_cfg()
     p = moe.init_moe(jax.random.PRNGKey(2), cfg, FFN.SWIGLU, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(3), (8, 1, cfg.d_model))
-    y_dense, _ = moe._dense_decode_moe(p, cfg, FFN.SWIGLU, x)
+    y_dense, _, _ = moe.apply_moe(p, cfg, FFN.SWIGLU, x)
     # grouped path on the same tokens laid out as one row of S=8
-    y_grouped, _ = moe.apply_moe(p, cfg, FFN.SWIGLU,
-                                 x.reshape(1, 8, cfg.d_model))
+    y_grouped, _, _ = moe.apply_moe(p, cfg, FFN.SWIGLU,
+                                    x.reshape(1, 8, cfg.d_model))
     np.testing.assert_allclose(np.asarray(y_dense.reshape(8, -1)),
                                np.asarray(y_grouped.reshape(8, -1)),
                                rtol=2e-4, atol=2e-4)

@@ -281,3 +281,30 @@ def test_gen_rest_plan_disjoint_groups():
         assert plan.parallel[t] == (1, 1, 2)
         want = [0, 1] if t == gen else [2, 3]
         assert plan.assignment[t].reshape(-1).tolist() == want
+
+
+def test_one_chip_plan_for_the_lfm2_cut():
+    """LLMSpec learns the expert width, the experts held and the layer
+    mix: the six-layer LFM2-8B-A1B cut with 8 of 32 experts held counts
+    669M weights (the published 32 experts x d_ff 7168 in every layer
+    would be 8.5B), and the scheduler finds a one-chip GRPO plan for the
+    cell's batch on a v5e."""
+    import dataclasses
+    from repro.configs import archs
+    cfg = archs.LFM2_8B_A1B
+    cut = dataclasses.replace(cfg, n_layers=6, pattern=cfg.pattern[:6],
+                              experts_held=8)
+    m = workflow.LLMSpec.from_model_config(cut)
+    assert (m.moe_layers, m.attn_layers, m.experts_held, m.expert_h2) == \
+        (4, 1, 8, 1792)
+    held = m.layer_weight_count * m.n_layers
+    assert 5.2e8 < held < 5.6e8          # layers only; 669M with embedding
+    assert m.layer_active_count < m.layer_weight_count
+    wf = workflow.make_grpo(m, global_batch=2, n_rollouts=8, seq_in=64,
+                            seq_out=128)
+    topo = topology.build_host(1, topology.TPU_V5E)
+    r = HybridScheduler(topo, wf, max_groupings=8,
+                        max_sizes_per_grouping=4).search(budget=40)
+    ok, msg = check_constraints(topo, wf, r.plan)
+    assert ok, msg
+    assert np.isfinite(r.cost)

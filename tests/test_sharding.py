@@ -110,6 +110,57 @@ def test_param_specs_jit_roundtrip_on_mesh():
                                    rtol=1e-5)
 
 
+_MOE_ON_MESH = """
+import jax, jax.numpy as jnp, numpy as np
+from dataclasses import replace
+from jax.sharding import Mesh
+from repro.configs import archs
+from repro.models import transformer as T
+from repro.parallel import sharding as sh
+for name in ("granite-moe-3b-a800m", "lfm2-8b-a1b"):
+    cfg = replace(archs.get(name, smoke=True), dtype="float32")
+    params = T.init_params(jax.random.PRNGKey(0), cfg)
+    tok = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
+                             cfg.vocab_size)
+    def loss(p):
+        out = T.forward(p, cfg, {"tokens": tok}, remat=False)
+        return jnp.mean(jnp.square(out["logits"]))
+    want, gw = jax.jit(jax.value_and_grad(loss))(params)
+    mesh = Mesh(np.array(jax.devices()).reshape(2, 2), ("data", "model"))
+    placed = jax.device_put(params, sh.named_shardings(
+        mesh, sh.param_tree_specs(params), params))
+    with mesh, sh.use_hints(sh.default_activation_rules()):
+        got, gg = jax.jit(jax.value_and_grad(loss))(placed)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(gg),
+                    jax.tree_util.tree_leaves(gw)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=1e-6)
+print("OK")
+"""
+
+
+def test_grouped_moe_on_a_sharded_mesh_matches_one_device():
+    """The dropless expert layer (sorted assignments, ``ragged_dot``) has
+    no activation hints of its own: on a 2x2 data/model mesh its
+    placement comes from the parameter rules (experts over data, their
+    width over model) and the compiler's propagation.  Loss and
+    gradients there equal one device's (float32; rtol 2e-4 covers the
+    sharded reductions' order).  Four forced host devices need their
+    own interpreter."""
+    import os
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"))
+    r = subprocess.run([sys.executable, "-c", _MOE_ON_MESH],
+                       capture_output=True, text=True, timeout=600,
+                       env=env, cwd=repo)
+    assert r.returncode == 0 and r.stdout.strip().endswith("OK"), r.stderr
+
+
 # -- hint ------------------------------------------------------------------
 
 def test_hint_identity_outside_rules():

@@ -142,3 +142,35 @@ def test_qwen3_decode_step_writes_cache_in_place(one_chip):
     copies = [l.strip()[:120] for b in bodies for l in comps.get(b, [])
               if per_layer.search(l)]
     assert not copies, copies
+
+
+def test_lfm2_actor_step_groups_experts_and_fits_one_chip(one_chip):
+    """The lfm2-8b-a1b.grpo-sync cell's actor step at the published
+    widths: the six-layer cut with 8 of the 32 experts held, 2 prompts x
+    8 rollouts of 64 + 128 tokens.  The held experts run as grouped
+    matrix products (the compiler's ragged dot, not a dense fallback),
+    and the step with the reference copy beside it fits the chip."""
+    c = archs.get("lfm2-8b-a1b")
+    cfg = dataclasses.replace(c, n_layers=6, pattern=c.pattern[:6],
+                              experts_held=8)
+    on_chip = lambda tree: jax.tree_util.tree_map(      # noqa: E731
+        lambda x: _shape(one_chip, x.shape, x.dtype), tree)
+    params = jax.eval_shape(lambda k: T.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(
+        lambda p: adam.init_adam_state(p, adam.AdamConfig()), params)
+    B, P, G = 16, 64, 128
+    batch = {"sequences": _shape(one_chip, (B, P + G), jnp.int32),
+             "logp_old": _shape(one_chip, (B, G), jnp.float32),
+             "advantages": _shape(one_chip, (B, G), jnp.float32),
+             "mask": _shape(one_chip, (B, G), jnp.float32)}
+    compiled = jax.jit(make_actor_step(cfg, RLConfig()),
+                       static_argnums=(3,)).lower(
+        on_chip(params), on_chip(opt), batch, P).compile()
+    assert "ragged-dot" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    ref_bytes = sum(x.size * x.dtype.itemsize
+                    for x in jax.tree_util.tree_leaves(params))
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes + ref_bytes)
+    assert total < HBM_BYTES, total / 1e9
