@@ -22,8 +22,7 @@ import numpy as np
 from repro.core import plan as plan_mod
 from repro.core.workflow import Task, TaskKind
 from repro.genserve import adapter as genserve
-from repro.obs import trace as obs_trace
-from repro.rl import gae, losses
+from repro.obs import metrics as obs_metrics, trace as obs_trace
 from repro.rl import rewards as rewards_mod
 
 _EXECUTORS: Dict[Tuple[TaskKind, Optional[str]], Callable] = {}
@@ -200,52 +199,46 @@ def run_critic_inference(st, bb, placement):
 # ---------------------------------------------------------------------------
 
 def ensure_train_batch(st, bb):
-    """KL-penalised rewards + advantages, computed once per iteration.
+    """KL-penalised rewards + advantages, computed once per iteration by
+    the trainer's one jitted program (``st._advantages``).
 
     Both training executors may run concurrently on disjoint GPU groups;
-    the first through the lock materializes the batch."""
+    the first through the lock materializes the batch.  On one device
+    nothing is read back to the host here: the batch's scalars stay on
+    the device until ``run_actor_training`` reads them with the actor
+    step's metrics, so the actor step is queued right behind this
+    program."""
     with bb["lock"]:
         if "batch" in bb:
             return
         with obs_trace.span("rl.advantages"):
-            rl = st.rl
             b = bb["bundle"]
             ro = b["rollout"]
-            # under multi-device execution the rollout, reference and critic
-            # tensors are committed to different meshes; eager ops refuse to
-            # mix them, so the (tiny) advantage prep runs from host copies —
-            # the training executors re-commit the batch onto their meshes
-            multidev = bool(bb.get("multidev"))
-            # round-trip through host memory drops the device commitment
-            # while keeping jnp semantics downstream
-            pull = (lambda x: jnp.asarray(np.asarray(x))) if multidev \
-                else (lambda x: x)
+            host_reads = obs_metrics.counter("rl.advantages.host_reads")
+
+            def pull(x):
+                # under multi-device execution the rollout, reference and
+                # critic tensors are committed to different meshes, which
+                # jit refuses to mix as eager ops do; a round trip through
+                # host memory drops the commitment, and the training
+                # executors re-commit the batch onto their meshes
+                if not bb.get("multidev"):
+                    return x
+                if isinstance(x, jax.Array):
+                    host_reads.inc()
+                return jnp.asarray(np.asarray(x))
             mask = pull(ro["mask"])
             lp_old = pull(ro["logprobs"])
-            lp_ref = pull(bb["lp_ref"])
-            tok_rewards, kl = losses.kl_penalised_rewards(
-                jnp.asarray(bb["scores"]), lp_old, lp_ref, mask,
-                kl_beta=rl.kl_beta)
-            bb["metrics"].update({
-                "reward_mean": float(bb["scores"].mean()),
-                "kl": float(kl),
-                "gen_len": float(np.asarray(mask).sum(1).mean()),
-            })
-            if rl.algorithm == "ppo":
-                values = pull(bb["values"])
-                adv, returns = gae.gae_advantages(
-                    tok_rewards, values * mask, mask,
-                    gamma=rl.gamma, lam=rl.lam)
-                bb["returns"] = returns
-            else:
-                seq_reward = np.asarray(tok_rewards).sum(1)
-                adv = gae.grpo_advantages(jnp.asarray(seq_reward),
-                                          rl.n_rollouts, mask)
-            if rl.whiten_advantages:
-                adv = gae.whiten(adv, mask)
+            values = pull(bb["values"]) if st.rl.algorithm == "ppo" \
+                else None
+            out = st._advantages(jnp.asarray(bb["scores"]), mask, lp_old,
+                                 pull(bb["lp_ref"]), values)
+            bb["returns"] = out["returns"]
+            bb["batch_metrics"] = {k: out[k] for k in
+                                   ("reward_mean", "kl", "gen_len")}
             bb["batch"] = {"sequences": pull(ro["sequences"]),
                            "logp_old": lp_old,
-                           "advantages": adv, "mask": mask}
+                           "advantages": out["advantages"], "mask": mask}
 
 
 @register(TaskKind.TRAIN, "actor_training")
@@ -268,7 +261,10 @@ def run_actor_training(st, bb, placement):
     if "moe_load" in am:
         st.count_moe_load("train", am.pop("moe_load"))
     with bb["lock"]:
-        bb["metrics"].update({k: float(v) for k, v in am.items()})
+        # the batch's scalars are read here too, after the actor step has
+        # been queued behind the program that made them
+        bb["metrics"].update({k: float(v) for k, v in
+                              {**bb["batch_metrics"], **am}.items()})
     return st.actor
 
 

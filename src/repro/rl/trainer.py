@@ -113,6 +113,31 @@ def make_actor_step(cfg: ModelConfig, rl: RLConfig) -> Callable:
     return actor_step
 
 
+def advantage_batch(scores, mask, logprobs, lp_ref, values=None, *,
+                    algorithm: str, n_rollouts: int, kl_beta: float,
+                    whiten_advantages: bool, gamma: float,
+                    lam: float) -> Dict[str, object]:
+    """The training batch's advantages from the rollouts' sequence
+    ``scores`` [B], ``mask``, actor and reference log-probs [B, T] (and
+    the critic's ``values`` for PPO): KL-penalised token rewards, then
+    GRPO's group-normalised or PPO's GAE advantages, optionally whitened.
+    Returns ``advantages``, ``returns`` (PPO; None for GRPO) and the
+    scalars ``kl``, ``gen_len`` and ``reward_mean``.  Meant to run under
+    ``jax.jit`` with every keyword static: nothing is read to the host."""
+    tok_rewards, kl = losses.kl_penalised_rewards(
+        scores, logprobs, lp_ref, mask, kl_beta=kl_beta)
+    returns = None
+    if algorithm == "ppo":
+        adv, returns = gae.gae_advantages(tok_rewards, values * mask, mask,
+                                          gamma=gamma, lam=lam)
+    else:
+        adv = gae.grpo_advantages(tok_rewards.sum(1), n_rollouts, mask)
+    if whiten_advantages:
+        adv = gae.whiten(adv, mask)
+    return {"advantages": adv, "returns": returns, "kl": kl,
+            "gen_len": mask.sum(1).mean(), "reward_mean": scores.mean()}
+
+
 class RLTrainer:
     def __init__(self, model_cfg: ModelConfig, rl_cfg: RLConfig,
                  task: AdditionTask, key, plan=None, topo=None, wf=None,
@@ -233,6 +258,14 @@ class RLTrainer:
         self._actor_step_raw = make_actor_step(cfg, rl)
         self._actor_step = jax.jit(self._actor_step_raw,
                                    static_argnames=("gen_start",))
+
+        def advantages(scores, mask, logprobs, lp_ref, values):
+            return advantage_batch(
+                scores, mask, logprobs, lp_ref, values,
+                algorithm=rl.algorithm, n_rollouts=rl.n_rollouts,
+                kl_beta=rl.kl_beta, whiten_advantages=rl.whiten_advantages,
+                gamma=rl.gamma, lam=rl.lam)
+        self._advantages = jax.jit(advantages)
 
         if rl.algorithm == "ppo":
             def critic_vals(critic, head, sequences, gen_start):

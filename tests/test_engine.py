@@ -9,6 +9,7 @@
   (one-step off-policy staleness is exactly one weight version).
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -195,6 +196,50 @@ def test_measured_vs_predicted_comparison():
     assert cmp["measured_iter_s"] > 0
     assert cmp["predicted_iter_s"] > 0
     assert np.isfinite(cmp["ratio"])
+
+
+def test_advantage_prep_reads_nothing_to_the_host(monkeypatch):
+    """One-device GRPO: ``ensure_train_batch`` reads no device array to
+    the host (the transfer guard holds it on an accelerator; on the CPU,
+    where a read is no transfer, fetching any ``jax.Array``'s value fails
+    instead), ``rl.advantages.host_reads`` stays 0, and the iteration's
+    kl, gen_len and reward_mean are the eager formulas' as floats."""
+    from repro.engine import tasks as tasks_mod
+    from repro.obs import metrics as obs_metrics
+    from repro.rl import losses
+    trainer, topo, plan = disaggregated_setup()
+    assert not trainer.engine.ctx.multidev
+    orig = tasks_mod.ensure_train_batch
+    seen = []
+
+    def fetch(self):
+        raise AssertionError("a device array was read to the host")
+
+    def guarded(st, bb):
+        if "batch" not in bb:
+            seen.append(bb)
+        with monkeypatch.context() as m:
+            m.setattr(type(jnp.zeros(())), "_value", property(fetch))
+            with jax.transfer_guard_device_to_host("disallow"):
+                orig(st, bb)
+    monkeypatch.setattr(tasks_mod, "ensure_train_batch", guarded)
+    reads = obs_metrics.counter("rl.advantages.host_reads")
+    before = reads.value
+    # the second iteration's actor differs from the reference: kl != 0
+    out = run_iters(trainer, 2)[-1]
+    assert len(seen) == 2 and reads.value == before
+    bb = seen[-1]
+    ro = bb["bundle"]["rollout"]
+    _, kl = losses.kl_penalised_rewards(
+        jnp.asarray(bb["scores"]), ro["logprobs"], bb["lp_ref"],
+        ro["mask"], kl_beta=trainer.rl.kl_beta)
+    want = {"kl": float(kl),
+            "gen_len": float(np.asarray(ro["mask"]).sum(1).mean()),
+            "reward_mean": float(bb["scores"].mean())}
+    assert want["kl"] != 0.0
+    for k, v in want.items():
+        assert type(out[k]) is float
+        assert out[k] == pytest.approx(v, rel=1e-6, abs=1e-7), k
 
 
 def test_device_folding_deterministic():

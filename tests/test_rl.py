@@ -75,6 +75,68 @@ def test_kl_penalised_rewards_places_score_at_last_token():
     assert float(kl) == 0.0
 
 
+def eager_batch(scores, mask, lp_old, lp_ref, values, *, algorithm,
+                n_rollouts, kl_beta, whiten, gamma, lam):
+    """The advantage preparation as separate eager ops, GRPO's sequence
+    reward summed on the host."""
+    tok_rewards, kl = losses.kl_penalised_rewards(
+        jnp.asarray(scores), lp_old, lp_ref, mask, kl_beta=kl_beta)
+    returns = None
+    if algorithm == "ppo":
+        adv, returns = gae.gae_advantages(tok_rewards, values * mask, mask,
+                                          gamma=gamma, lam=lam)
+    else:
+        seq_reward = np.asarray(tok_rewards).sum(1)
+        adv = gae.grpo_advantages(jnp.asarray(seq_reward), n_rollouts, mask)
+    if whiten:
+        adv = gae.whiten(adv, mask)
+    return {"advantages": adv, "returns": returns, "kl": float(kl),
+            "gen_len": float(np.asarray(mask).sum(1).mean()),
+            "reward_mean": float(scores.mean())}
+
+
+@pytest.mark.parametrize("whiten", [True, False])
+@pytest.mark.parametrize("algorithm", ["grpo", "ppo"])
+@pytest.mark.parametrize("masks", ["ragged", "full"])
+def test_jitted_advantage_batch_matches_eager(algorithm, whiten, masks):
+    """``advantage_batch`` under jit gives the eager formulas' batch to
+    float32 rounding: with a row whose mask is all zero (its score lands
+    on position 0) and rows valid to their last position."""
+    from repro.rl.trainer import advantage_batch
+    rng = np.random.default_rng(3)
+    B, G, T = 8, 4, 7
+    scores = rng.normal(size=(B,)).astype(np.float32)
+    lp_old = jnp.asarray(rng.normal(size=(B, T)) - 2.0, jnp.float32)
+    lp_ref = jnp.asarray(rng.normal(size=(B, T)) - 2.0, jnp.float32)
+    values = jnp.asarray(rng.normal(size=(B, T)), jnp.float32)
+    if masks == "ragged":
+        lens = np.array([0, T, 3, 1, T, 5, 2, T - 1])
+    else:
+        lens = np.full((B,), T)
+    mask = jnp.asarray(np.arange(T)[None, :] < lens[:, None], jnp.float32)
+    kw = dict(algorithm=algorithm, n_rollouts=G, kl_beta=0.05,
+              gamma=0.97, lam=0.9)
+    run = jax.jit(advantage_batch, static_argnames=(
+        "algorithm", "n_rollouts", "kl_beta", "whiten_advantages",
+        "gamma", "lam"))
+    got = run(jnp.asarray(scores), mask, lp_old, lp_ref,
+              values if algorithm == "ppo" else None,
+              whiten_advantages=whiten, **kw)
+    want = eager_batch(scores, mask, lp_old, lp_ref, values, whiten=whiten,
+                       **kw)
+    np.testing.assert_allclose(np.asarray(got["advantages"]),
+                               np.asarray(want["advantages"]),
+                               rtol=1e-5, atol=1e-5)
+    if algorithm == "ppo":
+        np.testing.assert_allclose(np.asarray(got["returns"]),
+                                   np.asarray(want["returns"]),
+                                   rtol=1e-5, atol=1e-5)
+    else:
+        assert got["returns"] is None
+    for k in ("kl", "gen_len", "reward_mean"):
+        assert float(got[k]) == pytest.approx(want[k], rel=1e-6, abs=1e-7)
+
+
 def tiny_cfg():
     return ModelConfig(name="tiny", n_layers=2, d_model=64, n_heads=2,
                        n_kv_heads=2, head_dim=32, d_ff=128,
