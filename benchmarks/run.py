@@ -95,8 +95,7 @@ def main() -> None:
                             fig4_loadbalance, fig5_search_efficiency,
                             fig6_small_scale_ilp, fig7_costmodel_validation,
                             fig8_training_quality, fig10_heterogeneity,
-                            genserve_throughput, obs_overhead,
-                            sharded_dispatch)
+                            genserve_throughput, obs_overhead)
     benches = [
         ("engine_throughput", "plan-driven engine, measured vs predicted",
          engine_throughput.run),
@@ -111,10 +110,6 @@ def main() -> None:
          "continuous batching vs single-wave decode; chunked admission; "
          "paged KV + prefix reuse; speculative decoding",
          genserve_throughput.run),
-        ("sharded_dispatch",
-         "sharded DP=2/TP=2 train step + gen/train overlap on 8 forced "
-         "host devices (subprocess)",
-         sharded_dispatch.run),
         ("fig3_e2e", "Figure 3: end-to-end throughput", fig3_e2e.run),
         ("fig4_loadbalance", "Figure 4: LB ablation", fig4_loadbalance.run),
         ("fig5_search_efficiency", "Figure 5", fig5_search_efficiency.run),

@@ -7,18 +7,22 @@ other 4, and validates the sharded execution path end to end:
 
 - group-aware folding is injective: GEN and TRAIN land on disjoint real
   device sets with zero collisions;
+- every model program keeps its name on the meshes: the modules lowered
+  are ``jit_generate``, ``jit_ref_logp``, ``jit_actor_step`` (and PPO's
+  ``jit_critic_vals`` and ``jit_critic_step``), as on one device;
 - the DP=2/TP=2 sharded train step matches an unsharded single-device
-  run numerically (loss within tolerance) and greedy generation is
-  token-identical;
+  run numerically (loss, and PPO's critic loss, within tolerance) and
+  greedy generation is token-identical;
 - async mode runs the GEN lane wall-clock concurrent with the training
   stages (``overlap_active``), the one-step-staleness invariant intact;
 - ``compare_with_simulator`` prices the realized parallelization.
 
-Run:  python examples/sharded_exec.py [--iters 4] [--json]
+Run:  python examples/sharded_exec.py [--iters 4] [--algorithm ppo] [--json]
 """
 import argparse
 import json
 import os
+import re
 import sys
 
 _FLAG = "--xla_force_host_platform_device_count=8"
@@ -41,6 +45,20 @@ from repro.models.config import ModelConfig             # noqa: E402
 from repro.rl.trainer import RLConfig, RLTrainer        # noqa: E402
 
 KEY = jax.random.PRNGKey(0)
+MODULE_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+
+class LoweredModules:
+    """Names of the modules JAX lowers while ``on`` is set, as the
+    profiler writes them (the event's ``jit(f)`` is module ``jit_f``)."""
+
+    def __init__(self):
+        self.names, self.on = set(), False
+        jax.monitoring.register_event_duration_secs_listener(self)
+
+    def __call__(self, event, duration, fun_name="", **kwargs):
+        if self.on and event == MODULE_EVENT:
+            self.names.add(re.sub(r"^jit\((\w+)\)$", r"jit_\1", fun_name))
 
 
 def tiny_cfg():
@@ -63,16 +81,17 @@ def build_plan_8dev(wf):
     return topo, plan
 
 
-def make_trainer(devices=None, greedy=False):
+def make_trainer(devices=None, greedy=False, algorithm="grpo"):
     cfg = tiny_cfg()
     task = AdditionTask(max_operand=9)
     # whitening off: it normalizes by the in-group advantage std, which
     # amplifies TP reduction-order noise (~1e-6) to O(1) when a group's
     # rewards are nearly uniform — parity would compare amplified noise
-    rl = RLConfig(algorithm="grpo", n_rollouts=4, max_new_tokens=4,
+    rl = RLConfig(algorithm=algorithm, n_rollouts=4, max_new_tokens=4,
                   asynchronous=True, greedy=greedy,
                   whiten_advantages=False)
-    wf = workflow.make_workflow("grpo", workflow.LLMSpec.from_model_config(cfg),
+    wf = workflow.make_workflow(algorithm,
+                                workflow.LLMSpec.from_model_config(cfg),
                                 synchronous=False, n_rollouts=rl.n_rollouts,
                                 seq_in=task.prompt_len,
                                 seq_out=rl.max_new_tokens, global_batch=1)
@@ -102,6 +121,7 @@ def run(trainer, iters, batch=4, seed=0):
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--iters", type=int, default=4)
+    ap.add_argument("--algorithm", choices=("grpo", "ppo"), default="grpo")
     # first trained iteration matches to ~1e-6; later iterations carry
     # compounded float32 TP reduction-order drift through the updated
     # params (observed ~1% by iteration 3 on the tiny model)
@@ -116,8 +136,9 @@ def main():
         f"need 8 forced host devices, got {n_dev} (XLA_FLAGS lost?)"
 
     # sharded run on all 8 devices, unsharded baseline pinned to one
-    sharded, topo, plan = make_trainer()
-    baseline, _, _ = make_trainer(devices=[jax.devices()[0]])
+    sharded, topo, plan = make_trainer(algorithm=args.algorithm)
+    baseline, _, _ = make_trainer(devices=[jax.devices()[0]],
+                                  algorithm=args.algorithm)
 
     eng = sharded.engine
     gen_t, train_t = eng.ctx.gen_task, eng.ctx.actor_train
@@ -133,6 +154,7 @@ def main():
     assert train_pl.mesh_shape == (2, 2), train_pl.mesh_shape
     assert (train_pl.dp_eff, train_pl.tp_eff) == (2, 2)
     assert gen_pl.mesh_shape == (2, 2)
+    assert all(pl.sharded for pl in eng.placements.values())
     assert eng.overlap_active(), "disjoint async groups must overlap"
     assert not baseline.engine.overlap_active()
     base_pl = baseline.engine.placements[train_t]
@@ -142,8 +164,17 @@ def main():
     # temperature sampling: in-group reward variance makes the GRPO
     # advantages (and so the train-step loss) non-trivially nonzero —
     # the parity below actually exercises the DP=2/TP=2 update
+    lowered = LoweredModules()
+    lowered.on = True
     m_sh, g_sh = run(sharded, args.iters)
+    lowered.on = False
     m_bl, g_bl = run(baseline, args.iters)
+    programs = ["generate", "ref_logp", "actor_step"]
+    if args.algorithm == "ppo":
+        programs += ["critic_vals", "critic_step"]
+    missing = {f"jit_{p}" for p in programs} - lowered.names
+    assert not missing, f"programs renamed on the meshes: {missing} not " \
+        f"among {sorted(lowered.names)}"
 
     assert m_sh[0].get("pipeline_fill") == 1.0   # async fill iteration
     for it, (a, b) in enumerate(zip(g_sh, g_bl)):
@@ -159,10 +190,16 @@ def main():
     rewards_sh = [m["reward_mean"] for m in m_sh[1:]]
     rewards_bl = [m["reward_mean"] for m in m_bl[1:]]
     np.testing.assert_allclose(rewards_sh, rewards_bl, rtol=1e-6)
+    critic_sh = [m.get("critic_loss") for m in m_sh[1:]]
+    critic_bl = [m.get("critic_loss") for m in m_bl[1:]]
+    if args.algorithm == "ppo":
+        np.testing.assert_allclose(critic_sh, critic_bl,
+                                   rtol=args.loss_rtol, atol=args.loss_atol)
 
     # -- greedy decode: token-identical across meshes ------------------
-    greedy_sh, _, _ = make_trainer(greedy=True)
-    greedy_bl, _, _ = make_trainer(devices=[jax.devices()[0]], greedy=True)
+    greedy_sh, _, _ = make_trainer(greedy=True, algorithm=args.algorithm)
+    greedy_bl, _, _ = make_trainer(devices=[jax.devices()[0]], greedy=True,
+                                   algorithm=args.algorithm)
     _, gg_sh = run(greedy_sh, 2)
     _, gg_bl = run(greedy_bl, 2)
     for it, (a, b) in enumerate(zip(gg_sh, gg_bl)):
@@ -176,6 +213,7 @@ def main():
     cmp = eng.compare_with_simulator()
     occ = eng.wave_occupancy_summary()
     summary = {
+        "algorithm": args.algorithm,
         "devices": n_dev,
         "gen_devices": sorted(gen_ids),
         "train_devices": sorted(train_ids),
@@ -184,6 +222,10 @@ def main():
         "overlap_active": eng.overlap_active(),
         "loss_sharded": losses_sh,
         "loss_baseline": losses_bl,
+        "critic_loss_sharded": critic_sh,
+        "critic_loss_baseline": critic_bl,
+        "programs": sorted(f"jit_{p}" for p in programs),
+        "lowered_modules": sorted(lowered.names),
         "tokens_identical": True,
         "measured_iter_s": cmp["measured_iter_s"],
         "predicted_iter_s": cmp["predicted_iter_s"],
@@ -201,6 +243,9 @@ def main():
         for it, (ls, lb) in enumerate(zip(losses_sh, losses_bl), start=1):
             print(f"iter {it}: loss sharded={ls:+.6f} "
                   f"baseline={lb:+.6f}  delta={ls - lb:+.2e}")
+        if args.algorithm == "ppo":
+            print(f"critic loss sharded={critic_sh} baseline={critic_bl}")
+        print(f"programs on the meshes: {summary['programs']}")
         print("greedy generation token-identical across all iterations")
         print(f"measured {cmp['measured_iter_s']:.4f}s/iter, "
               f"predicted {cmp['predicted_iter_s']:.4f}s "

@@ -174,8 +174,11 @@ class Engine:
             and self.wf.task(t).name.startswith("actor"))
         dev_free = {int(d): 0.0 for t in range(self.wf.n_tasks)
                     for d in plan.assignment[t].reshape(-1)}
-        multidev = len({id(d) for pl in placements.values()
-                        for d in pl.local_devices}) > 1
+        n_devices = len({id(d) for pl in placements.values()
+                         for d in pl.local_devices})
+        for pl in placements.values():
+            pl.plan_n_devices = n_devices
+        multidev = n_devices > 1
         # commit each task's state onto its owning placement (no-op on
         # single-device hosts; rebuilds on every elastic plan swap)
         install = getattr(self.state, "install_placements", None)

@@ -76,6 +76,9 @@ class TaskPlacement:
     mesh: Mesh                      # ("data", "model") over local_devices
     rep_plan_devices: Tuple[int, ...] = ()  # plan id per distinct device
     collision: bool = False       # group shares a real device with another
+    # real devices the whole plan spans (the engine sets it with the
+    # plan's placements)
+    plan_n_devices: int = dataclasses.field(default=1, init=False)
 
     @property
     def mesh_shape(self) -> Tuple[int, int]:
@@ -128,6 +131,26 @@ class TaskPlacement:
     def shard_batch(self, tree):
         """Commit a batch pytree onto this mesh, split over ``data``."""
         return jax.device_put(tree, self.batch_shardings(tree))
+
+    def put(self, tree):
+        """Place a task's inputs for its program: split over ``data`` on
+        a mesh of several devices; moved onto the task's one device when
+        the plan spans others (the inputs may be committed to another
+        group's devices); left as they are on a one-device plan."""
+        return self._place(tree, self.batch_shardings)
+
+    def put_params(self, params):
+        """Place a parameter tree that another task owns (and updates)
+        for this task's program, as ``put`` places inputs: onto this
+        placement's parameter shardings on a mesh."""
+        return self._place(params, self.param_shardings)
+
+    def _place(self, tree, shardings):
+        if self.sharded:
+            return jax.device_put(tree, shardings(tree))
+        if self.plan_n_devices > 1:
+            return jax.device_put(tree, self.local_devices[0])
+        return tree
 
 
 def fold_plan(plan: Plan, local_devices) -> DeviceFolding:

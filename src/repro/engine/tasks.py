@@ -112,21 +112,10 @@ def run_generation(st, bb, placement):
                 prefill_chunk=getattr(st.rl, "prefill_chunk", 0),
                 fast_path=False, slot_failures=slot_failures,
                 spec_k=spec_k, draft_params=draft_params,
-                draft_cfg=draft_cfg,
-                mesh=placement.mesh if placement.sharded else None)
-        elif placement.sharded:
-            # single-wave decode jitted on the gen mesh: prompts split
-            # over data, params TP-sharded, hints active
-            prompts_d = placement.shard_batch(prompts)
-            ro = st.sharded_generate(placement, prompts_d)(
-                st.gen_params, prompts_d, bb["rng"])
-            stats = genserve.wave_stats_from_mask(ro["mask"])
+                draft_cfg=draft_cfg, mesh=placement.mesh)
         else:
-            if bb.get("multidev"):
-                prompts = jax.device_put(prompts,
-                                         placement.local_devices[0])
-            ro = st._generate(st.gen_params, prompts=prompts,
-                              rng=bb["rng"])
+            ro = st.program("generate", placement)(
+                st.gen_params, placement.put(prompts), bb["rng"])
             # the single-wave path decodes all B rows at once — its wave
             # width is B, whatever the cost model's bound says
             stats = genserve.wave_stats_from_mask(ro["mask"])
@@ -158,16 +147,8 @@ def run_reference(st, bb, placement):
     b = bb["bundle"]
     seqs = b["rollout"]["sequences"]
     with placement.mesh:
-        if placement.sharded:
-            seqs = placement.shard_batch(seqs)
-            lp_ref = st.sharded_ref_logp(placement, seqs)(
-                st.ref, seqs, int(b["gen_start"]))
-        else:
-            if bb.get("multidev"):
-                # rollout tensors live on the gen group's devices; pull
-                # them onto this task's device before the jitted call
-                seqs = jax.device_put(seqs, placement.local_devices[0])
-            lp_ref = st._ref_logp(st.ref, seqs, gen_start=b["gen_start"])
+        lp_ref = st.program("ref_logp", placement)(
+            st.ref, placement.put(seqs), int(b["gen_start"]))
     with bb["lock"]:
         # host copy under multi-device: the advantage prep mixes tensors
         # from several meshes, which eager ops refuse to colocate
@@ -180,15 +161,11 @@ def run_critic_inference(st, bb, placement):
     b = bb["bundle"]
     seqs = b["rollout"]["sequences"]
     with placement.mesh:
-        if placement.sharded:
-            seqs = placement.shard_batch(seqs)
-            values = st.sharded_critic_vals(placement, seqs)(
-                st.critic, st.value_head, seqs, int(b["gen_start"]))
-        else:
-            if bb.get("multidev"):
-                seqs = jax.device_put(seqs, placement.local_devices[0])
-            values = st._critic_vals(st.critic, st.value_head, seqs,
-                                     gen_start=b["gen_start"])
+        # the critic's weights live on critic_training's placement
+        values = st.program("critic_vals", placement)(
+            placement.put_params(st.critic),
+            placement.put_params(st.value_head), placement.put(seqs),
+            int(b["gen_start"]))
     with bb["lock"]:
         bb["values"] = np.asarray(values) if bb.get("multidev") else values
     return values
@@ -246,17 +223,9 @@ def run_actor_training(st, bb, placement):
     ensure_train_batch(st, bb)
     b = bb["bundle"]
     with placement.mesh:
-        if placement.sharded:
-            batch = placement.shard_batch(bb["batch"])
-            st.actor, st.actor_opt, am = st.sharded_actor_step(
-                placement, batch)(st.actor, st.actor_opt, batch,
-                                  int(b["gen_start"]))
-        else:
-            batch = bb["batch"]
-            if bb.get("multidev"):
-                batch = jax.device_put(batch, placement.local_devices[0])
-            st.actor, st.actor_opt, am = st._actor_step(
-                st.actor, st.actor_opt, batch, gen_start=b["gen_start"])
+        st.actor, st.actor_opt, am = st.program("actor_step", placement)(
+            st.actor, st.actor_opt, placement.put(bb["batch"]),
+            int(b["gen_start"]))
     am = dict(am)
     if "moe_load" in am:
         st.count_moe_load("train", am.pop("moe_load"))
@@ -279,20 +248,10 @@ def run_critic_training(st, bb, placement):
     cbatch = dict(bb["batch"], values_old=values * mask,
                   returns=bb["returns"])
     with placement.mesh:
-        if placement.sharded:
-            cbatch = placement.shard_batch(cbatch)
-            (st.critic, st.value_head), st.critic_opt, closs = \
-                st.sharded_critic_step(placement, cbatch)(
-                    (st.critic, st.value_head), st.critic_opt, cbatch,
-                    int(b["gen_start"]))
-        else:
-            if bb.get("multidev"):
-                cbatch = jax.device_put(cbatch,
-                                        placement.local_devices[0])
-            (st.critic, st.value_head), st.critic_opt, closs = \
-                st._critic_step((st.critic, st.value_head),
-                                st.critic_opt, cbatch,
-                                gen_start=b["gen_start"])
+        (st.critic, st.value_head), st.critic_opt, closs = st.program(
+            "critic_step", placement)(
+                (st.critic, st.value_head), st.critic_opt,
+                placement.put(cbatch), int(b["gen_start"]))
     with bb["lock"]:
         bb["metrics"]["critic_loss"] = float(closs)
     return closs

@@ -165,12 +165,12 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool,
         "arch": arch, "shape": shape_name, "multi_pod": multi_pod,
         "n_devices": int(n_dev),
         "compile_s": round(time.time() - t0, 1),
-        "flops_xla_raw": float(cost.get("flops", 0.0)),
+        "flops_xla_bodies_once": float(cost.get("flops", 0.0)),
         "flops_per_device": float(la.flops),
         "bytes_per_device": float(la.bytes),
         "collective_bytes_per_device": {k: float(v)
                                         for k, v in la.collectives.items()},
-        "collective_bytes_raw": {k: float(v) for k, v in coll.items()},
+        "collective_bytes_bodies_once": {k: float(v) for k, v in coll.items()},
         "params": int(cfg.param_count()),
         "active_params": int(cfg.active_param_count()),
         "seq_len": shape.seq_len, "global_batch": shape.global_batch,

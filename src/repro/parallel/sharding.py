@@ -25,11 +25,12 @@ Plan → mesh → sharding flow (how the engine uses this module):
 - ``TaskPlacement.param_shardings`` runs ``param_tree_specs`` →
   ``named_shardings`` on that mesh; ``rl.trainer`` commits each task's
   state (params + optimizer moments) onto its owner's shardings with
-  ``device_put`` and jits the step functions with explicit
-  in/out_shardings (jit refuses mismatched committed arrays, so the
-  device_put is load-bearing, not an optimization).
-- batches are committed with ``TaskPlacement.shard_batch`` (leading dim
-  over ``data``); step bodies trace inside ``use_hints(rules)`` under
+  ``device_put``, and ``RLTrainer.program`` jits each model program
+  for a mesh with those shardings as its state's in/out_shardings (jit
+  refuses mismatched committed arrays, so the device_put is
+  load-bearing, not an optimization).
+- batches are committed with ``TaskPlacement.put`` (leading dim over
+  ``data``); program bodies trace inside ``use_hints(rules)`` under
   ``with mesh:`` so ``hint`` constraints resolve (a bare
   ``PartitionSpec`` constraint needs an ambient mesh at trace time).
 - train → gen weight publication is an explicit ``device_put`` onto the

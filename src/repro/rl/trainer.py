@@ -16,6 +16,8 @@ public API and metrics dict are unchanged from the pre-engine version.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import inspect
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -163,10 +165,10 @@ class RLTrainer:
             max_new_tokens=rl_cfg.max_new_tokens,
             temperature=rl_cfg.temperature, eos_token=EOS,
             greedy=rl_cfg.greedy)
-        self._shard_cache: Dict[Tuple, Callable] = {}
+        self._programs: Dict[Tuple, Callable] = {}   # (name, mesh)
         # device-side expert-load tallies by phase (count_moe_load)
         self._moe_load: Dict[str, jnp.ndarray] = {}
-        self._jit()
+        self._define_programs()
 
         # plan-driven engine: the plan decides task colocation/concurrency
         # and the sync path; without one, execute the colocate-all default.
@@ -235,29 +237,27 @@ class RLTrainer:
             self.value_head = tree["value_head"]
             self.critic_opt = tree["critic_opt"]
 
-    # ------------------------------------------------------------------
-    def _jit(self):
-        cfg, rl = self.cfg, self.rl
+    # -- model programs (plan -> placement -> one jitted program) --------
+    #
+    # Each model program is defined once and jitted per placement mesh by
+    # ``program``.  On one device it is the plain jit; on a placement of
+    # several devices its state carries the placement's shardings (state
+    # committed by ``install_placements``, inputs by ``TaskPlacement.put``)
+    # and its body traces under the placement's activation hints.
 
-        sampler = self.sampler
+    def _define_programs(self):
+        cfg, rl, sampler = self.cfg, self.rl, self.sampler
 
-        # a named function, so that the program is ``jit_generate`` in a
-        # profiler trace (a jitted ``functools.partial`` has no name)
         def generate(params, prompts, rng):
             return rollout.generate(params, cfg, prompts, rng, sampler)
-        self._generate_raw = generate
-        self._generate = jax.jit(generate)
 
         def ref_logp(params, sequences, gen_start):
             lp, _ = rollout.sequence_logprobs(params, cfg, sequences,
                                               gen_start)
             return lp
-        self._ref_logp_raw = ref_logp
-        self._ref_logp = jax.jit(ref_logp, static_argnames=("gen_start",))
 
-        self._actor_step_raw = make_actor_step(cfg, rl)
-        self._actor_step = jax.jit(self._actor_step_raw,
-                                   static_argnames=("gen_start",))
+        self._fns = {"generate": generate, "ref_logp": ref_logp,
+                     "actor_step": make_actor_step(cfg, rl)}
 
         def advantages(scores, mask, logprobs, lp_ref, values):
             return advantage_batch(
@@ -271,9 +271,6 @@ class RLTrainer:
             def critic_vals(critic, head, sequences, gen_start):
                 return rewards_mod.critic_values(critic, head, cfg,
                                                  sequences, gen_start)
-            self._critic_vals_raw = critic_vals
-            self._critic_vals = jax.jit(critic_vals,
-                                        static_argnames=("gen_start",))
 
             def critic_loss(cp, batch, gen_start):
                 critic, head = cp
@@ -289,31 +286,65 @@ class RLTrainer:
                 new_cp, new_opt, _ = adam.adam_update(
                     cp, grads, opt_state, adam.AdamConfig(lr=rl.lr))
                 return new_cp, new_opt, loss
-            self._critic_step_raw = critic_step
-            self._critic_step = jax.jit(critic_step,
-                                        static_argnames=("gen_start",))
+            self._fns.update(critic_vals=critic_vals,
+                             critic_step=critic_step)
 
-    # -- sharded execution (plan → mesh → shardings) --------------------
-    #
-    # When a task's placement spans more than one real device, its state
-    # is committed onto the placement mesh (``install_placements``) and
-    # the step runs through a per-mesh jit with explicit in/out
-    # shardings + ``use_hints`` activation rules.  jit refuses committed
-    # arrays whose sharding mismatches ``in_shardings``, so callers go
-    # through ``TaskPlacement.shard_batch`` for inputs; single-device
-    # placements keep the original jitted paths untouched.
+    def program(self, name: str, placement) -> Callable:
+        """The jitted model program ``name`` (``generate``, ``ref_logp``,
+        ``actor_step``, ``critic_vals`` or ``critic_step``) for
+        ``placement``, built once per placement mesh.  It takes the
+        program's state, then its inputs, then ``gen_start`` as a
+        positional static ``int``; the profiler names it
+        ``jit_<name>`` on every placement."""
+        key = (name, placement.mesh)
+        fn = self._programs.get(key)
+        if fn is None:
+            fn = self._programs[key] = self._build_program(name, placement)
+        return fn
+
+    def _build_program(self, name: str, placement) -> Callable:
+        fn = self._fns[name]
+        static = tuple(i for i, a in
+                       enumerate(inspect.signature(fn).parameters)
+                       if a == "gen_start")
+        if not placement.sharded:
+            return jax.jit(fn, static_argnums=static)
+        rules = placement.activation_rules()
+
+        @functools.wraps(fn)
+        def hinted(*args):
+            with sh.use_hints(rules):
+                return fn(*args)
+        state = self._state_shardings(name, placement)
+        repl = placement.replicated_sharding()
+        # a None leaf keeps the sharding ``placement.put`` committed the
+        # batch with; generate's rng is replicated
+        inputs = (None, repl) if name == "generate" else (None,)
+        out = (*state, repl) if name.endswith("_step") else repl
+        return jax.jit(hinted, static_argnums=static,
+                       in_shardings=(*state, *inputs), out_shardings=out)
+
+    def _state_shardings(self, name: str, placement) -> Tuple:
+        """Shardings of the state a program takes ahead of its inputs:
+        parameters by ``parallel.sharding``'s rules, the optimizer's
+        moments alongside them."""
+        ps = placement.param_shardings
+        if name == "generate":
+            return (ps(self.gen_params),)
+        if name == "ref_logp":
+            return (ps(self.ref),)
+        if name == "actor_step":
+            p = ps(self.actor)
+            return (p, self._opt_shardings(placement, p))
+        cps = (ps(self.critic), ps(self.value_head))
+        if name == "critic_vals":
+            return cps
+        return (cps, self._opt_shardings(placement, cps))
 
     def _opt_shardings(self, placement, pshard):
         pshard = adam.partition(pshard)[0]
         return {"step": placement.replicated_sharding(),
                 "m": pshard, "v": pshard}
-
-    def _sharded(self, name: str, placement, build: Callable) -> Callable:
-        key = (name, placement.mesh)
-        fn = self._shard_cache.get(key)
-        if fn is None:
-            fn = self._shard_cache[key] = build()
-        return fn
 
     def install_placements(self, placements, wf) -> None:
         """Commit each task's state onto its owning placement.
@@ -324,120 +355,37 @@ class RLTrainer:
         generation replica → actor_generation; critic state →
         critic_training.  Single-host single-device runs are left
         untouched (everything already lives on the only device)."""
-        self._shard_cache.clear()
+        self._programs.clear()
         multi = len({id(d) for pl in placements.values()
                      for d in pl.local_devices}) > 1
+        if not multi:
+            return
         by_name = {wf.tasks[t].name: pl for t, pl in placements.items()}
 
         tp = by_name.get("actor_training")
-        if tp is not None and (tp.sharded or multi):
+        if tp is not None:
             ps = tp.param_shardings(self.actor)
             self.actor = jax.device_put(self.actor, ps)
             self.actor_opt = jax.device_put(
                 self.actor_opt, self._opt_shardings(tp, ps))
         rp = by_name.get("reference_inference")
-        if rp is not None and (rp.sharded or multi):
+        if rp is not None:
             self.ref = jax.device_put(self.ref,
                                       rp.param_shardings(self.ref))
         gp = by_name.get("actor_generation")
-        if gp is not None and (gp.sharded or multi):
+        if gp is not None:
             self.gen_params = jax.device_put(
                 self.gen_params, gp.param_shardings(self.gen_params))
         if self.rl.algorithm == "ppo":
             cp = by_name.get("critic_training") \
                 or by_name.get("critic_inference")
-            if cp is not None and (cp.sharded or multi):
+            if cp is not None:
                 cps = (cp.param_shardings(self.critic),
                        cp.param_shardings(self.value_head))
                 self.critic, self.value_head = jax.device_put(
                     (self.critic, self.value_head), cps)
                 self.critic_opt = jax.device_put(
                     self.critic_opt, self._opt_shardings(cp, cps))
-
-    def sharded_actor_step(self, placement, batch) -> Callable:
-        """(params, opt, batch, gen_start) jitted on the placement mesh;
-        gen_start positional-static (jit forbids kwargs with
-        in_shardings)."""
-        def build():
-            rules = placement.activation_rules()
-            ps = placement.param_shardings(self.actor)
-            repl = placement.replicated_sharding()
-            os_ = self._opt_shardings(placement, ps)
-            bs = placement.batch_shardings(batch)
-            raw = self._actor_step_raw
-
-            def step(params, opt_state, batch, gen_start):
-                with sh.use_hints(rules):
-                    return raw(params, opt_state, batch, gen_start)
-            return jax.jit(step, static_argnums=(3,),
-                           in_shardings=(ps, os_, bs),
-                           out_shardings=(ps, os_, repl))
-        return self._sharded("actor_step", placement, build)
-
-    def sharded_ref_logp(self, placement, sequences) -> Callable:
-        def build():
-            rules = placement.activation_rules()
-            ps = placement.param_shardings(self.ref)
-            repl = placement.replicated_sharding()
-            bs = placement.batch_shardings(sequences)
-            raw = self._ref_logp_raw
-
-            def step(params, sequences, gen_start):
-                with sh.use_hints(rules):
-                    return raw(params, sequences, gen_start)
-            return jax.jit(step, static_argnums=(2,),
-                           in_shardings=(ps, bs), out_shardings=repl)
-        return self._sharded("ref_logp", placement, build)
-
-    def sharded_generate(self, placement, prompts) -> Callable:
-        """(params, prompts, rng) single-wave decode on the gen mesh."""
-        def build():
-            rules = placement.activation_rules()
-            ps = placement.param_shardings(self.gen_params)
-            repl = placement.replicated_sharding()
-            bs = placement.batch_shardings(prompts)
-            raw = self._generate_raw
-
-            def step(params, prompts, rng):
-                with sh.use_hints(rules):
-                    return raw(params, prompts=prompts, rng=rng)
-            return jax.jit(step, in_shardings=(ps, bs, repl),
-                           out_shardings=repl)
-        return self._sharded("generate", placement, build)
-
-    def sharded_critic_vals(self, placement, sequences) -> Callable:
-        def build():
-            rules = placement.activation_rules()
-            cps = (placement.param_shardings(self.critic),
-                   placement.param_shardings(self.value_head))
-            repl = placement.replicated_sharding()
-            bs = placement.batch_shardings(sequences)
-            raw = self._critic_vals_raw
-
-            def step(critic, head, sequences, gen_start):
-                with sh.use_hints(rules):
-                    return raw(critic, head, sequences, gen_start)
-            return jax.jit(step, static_argnums=(3,),
-                           in_shardings=(*cps, bs), out_shardings=repl)
-        return self._sharded("critic_vals", placement, build)
-
-    def sharded_critic_step(self, placement, batch) -> Callable:
-        def build():
-            rules = placement.activation_rules()
-            cps = (placement.param_shardings(self.critic),
-                   placement.param_shardings(self.value_head))
-            repl = placement.replicated_sharding()
-            os_ = self._opt_shardings(placement, cps)
-            bs = placement.batch_shardings(batch)
-            raw = self._critic_step_raw
-
-            def step(cp, opt_state, batch, gen_start):
-                with sh.use_hints(rules):
-                    return raw(cp, opt_state, batch, gen_start)
-            return jax.jit(step, static_argnums=(3,),
-                           in_shardings=(cps, os_, bs),
-                           out_shardings=(cps, os_, repl))
-        return self._sharded("critic_step", placement, build)
 
     # -- engine hooks ---------------------------------------------------
     def prepare_inputs(self, prompts: np.ndarray, answers: np.ndarray,

@@ -198,6 +198,22 @@ def test_measured_vs_predicted_comparison():
     assert np.isfinite(cmp["ratio"])
 
 
+def test_placement_put_moves_inputs_only_when_the_plan_spans_devices():
+    """On a one-device plan ``put`` and ``put_params`` hand the inputs
+    back untouched (no transfer, no commitment); a one-device placement
+    in a plan over several devices commits them onto its device."""
+    trainer, topo, plan = disaggregated_setup()
+    pl = trainer.engine.placements[trainer.engine.ctx.actor_train]
+    tree = {"x": jnp.ones((4, 3)), "y": np.ones(2, np.float32)}
+    assert not pl.sharded and pl.plan_n_devices == 1
+    assert pl.put(tree) is tree and pl.put_params(tree) is tree
+    pl.plan_n_devices = 2          # as the engine sets it for such a plan
+    for out in (pl.put(tree), pl.put_params(tree)):
+        for leaf in out.values():
+            assert leaf.committed
+            assert leaf.sharding.device_set == set(pl.local_devices)
+
+
 def test_advantage_prep_reads_nothing_to_the_host(monkeypatch):
     """One-device GRPO: ``ensure_train_batch`` reads no device array to
     the host (the transfer guard holds it on an accelerator; on the CPU,

@@ -195,3 +195,49 @@ def test_reward_partial_credit():
     assert task.reward(12, np.array([1, 2, EOS])) == 1.0
     assert 0 < task.reward(12, np.array([1, 3, EOS])) < 1.0
     assert task.reward(12, np.array([PAD, PAD, PAD])) == 0.0
+
+
+PROGRAM_TASKS = {"generate": "actor_generation",
+                 "ref_logp": "reference_inference",
+                 "actor_step": "actor_training",
+                 "critic_vals": "critic_inference",
+                 "critic_step": "critic_training"}
+
+
+@pytest.fixture(scope="module")
+def ppo_trainer():
+    task = AdditionTask(max_operand=9)
+    rl = RLConfig(algorithm="ppo", n_rollouts=4, max_new_tokens=4)
+    return RLTrainer(tiny_cfg(), rl, task, KEY, devices=jax.devices()[:1])
+
+
+@pytest.mark.parametrize("name", sorted(PROGRAM_TASKS))
+def test_one_device_program_is_plain_and_named(ppo_trainer, name):
+    """On a one-device placement each model program is the plain jit
+    (no sharding anywhere in its module) and keeps its own name."""
+    st = ppo_trainer
+    wf = st.wf
+    t = next(i for i in range(wf.n_tasks)
+             if wf.task(i).name == PROGRAM_TASKS[name])
+    placement = st.engine.placements[t]
+    assert not placement.sharded and placement.plan_n_devices == 1
+    B, P, T = 4, st.task.prompt_len, st.rl.max_new_tokens
+
+    def arr(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    seqs = arr(B, P + T, dtype=jnp.int32)
+    batch = {"sequences": seqs, "logp_old": arr(B, T),
+             "advantages": arr(B, T), "mask": arr(B, T)}
+    args = {
+        "generate": (st.gen_params, arr(B, P, dtype=jnp.int32),
+                     jax.random.PRNGKey(1)),
+        "ref_logp": (st.ref, seqs, P),
+        "actor_step": (st.actor, st.actor_opt, batch, P),
+        "critic_vals": (st.critic, st.value_head, seqs, P),
+        "critic_step": ((st.critic, st.value_head), st.critic_opt,
+                        dict(batch, values_old=arr(B, T),
+                             returns=arr(B, T)), P),
+    }[name]
+    text = st.program(name, placement).lower(*args).as_text()
+    assert text.startswith(f"module @jit_{name} "), text[:120]
+    assert "mhlo.sharding" not in text and "sdy.sharding" not in text
